@@ -22,7 +22,13 @@ class NaiveExecutor:
         self.catalog = catalog
         self.fact = fact
 
-    def execute(self, q: Query, measure_col: str | None = None):
+    def execute(self, q: Query, measure_col: str | None = None, op: str = "sum"):
+        """Aggregate of ``q`` over the filtered wide table.
+
+        ``op="sum"`` sums the measure (or counts rows without one);
+        ``op="max"`` takes the largest measure per group, ``-inf`` for a
+        group no row reaches (the tropical ring's 0̄).
+        """
         cat = self.catalog
         fact = cat.get(self.fact, q.version_of(self.fact))
         n = fact.num_rows
@@ -65,13 +71,17 @@ class NaiveExecutor:
             if q.measure and q.measure[0] == self.fact
             else np.ones(n, np.float32)
         )
-        vals = np.where(mask, vals, 0.0)
-        if not q.group_by:
-            return np.array(vals.sum(dtype=np.float64))
         dims = [self.catalog.domains()[a] for a in q.group_by]
-        flat = np.ravel_multi_index(tuple(cols[a].astype(np.int64) for a in q.group_by), dims)
-        out = np.zeros(int(np.prod(dims)))
-        np.add.at(out, flat, vals)
+        flat = (
+            np.ravel_multi_index(tuple(cols[a].astype(np.int64) for a in q.group_by), dims)
+            if q.group_by else np.zeros(n, np.int64)
+        )
+        if op == "max":
+            out = np.full(int(np.prod(dims)), -np.inf)
+            np.maximum.at(out, flat[mask], vals[mask])
+        else:
+            out = np.zeros(int(np.prod(dims)))
+            np.add.at(out, flat, np.where(mask, vals, 0.0))
         return out.reshape(dims)
 
 

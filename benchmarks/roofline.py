@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.mode import resolve_interpret
 from repro.kernels.segment_aggregate import ops as seg_ops
 from repro.kernels.segment_aggregate.ref import segment_aggregate_ref
 from repro.kernels.semiring_contract import ops as sc_ops
@@ -56,7 +57,7 @@ from repro.kernels.tropical_contract.ref import tropical_contract_ref
 
 from .common import emit, seeded_rng, time_fn
 
-INTERPRET = jax.default_backend() != "tpu"
+INTERPRET = resolve_interpret(None)
 
 COST_FLOOR = 1 << 19
 COST_CAP = 1 << 24

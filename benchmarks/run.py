@@ -52,6 +52,9 @@ def _write_json(key: str, rows) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     want = set(sys.argv[1:])
     failures = []
     print("name,us_per_call,derived")
@@ -78,9 +81,10 @@ def main() -> None:
     if not want or "roofline" in want:
         try:
             from . import roofline
-            print("# === roofline (from dry-run artifacts) ===")
+            print("# === roofline: kernel microbench ===")
             roofline.main()
         except Exception:
+            failures.append("roofline")
             traceback.print_exc()
     if failures:
         print(f"# FAILURES: {failures}")

@@ -20,6 +20,7 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from benchmarks.baselines import NaiveExecutor  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import (  # noqa: E402
     DashboardSpec, Drill, SetFilter, Treant, Undo, VizSpec, jt_from_catalog,
 )
@@ -80,4 +81,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FactorizedLinearRegression, FeatureSpec
 from repro.relational import schema
 
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

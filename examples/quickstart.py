@@ -5,6 +5,7 @@
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Query, Treant, jt_from_catalog
 from repro.core import semiring as sr
 from repro.relational import schema
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -787,7 +787,7 @@ class CJTEngine:
             vals = jax.tree_util.tree_map(
                 lambda a, z: jnp.concatenate([a, z], axis=0), vals, zeros
             )
-        if self.plans is not None and self.plans._shard_arity(rel) > 1:
+        if self.plans is not None and self.plans.plan_shards > 1:
             # commit the cached lift to the row-shard placement so sharded
             # plans consume it without a per-dispatch reshard copy
             vals = dist.place_rows(vals, self.plans.mesh, self.plans.mesh_axis)

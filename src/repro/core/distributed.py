@@ -14,9 +14,7 @@ mesh acquisition, the ring → collective mapping, and the row placement
 helpers.
 
 All acquisition is lazy: importing this module must never touch devices
-(CI hosts without a mesh import it fine), and ``shard_map`` is resolved on
-first use to absorb the jax API drift (moved out of ``experimental``
-around 0.6; ``check_rep`` renamed to ``check_vma``).
+(CI hosts without a mesh import it fine).
 
 Domain sharding (chain demo)
 ----------------------------
@@ -40,37 +38,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # Name of the 1-D mesh axis the engine row-shards over.
 SHARD_AXIS = "shard"
 
-# Lazily-resolved shard_map entry point (jax moved it out of experimental
-# around 0.6; resolving at import time would pin the API before user code
-# can configure the platform).
-_shard_map_fn = None
-
-
-def _resolve_shard_map():
-    global _shard_map_fn
-    if _shard_map_fn is None:
-        fn = getattr(jax, "shard_map", None)
-        if fn is None:
-            from jax.experimental.shard_map import shard_map as fn
-        _shard_map_fn = fn
-    return _shard_map_fn
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions.
-
-    The engine's local bodies run interpret-mode Pallas kernels, for which
-    jax has no replication rule — ``check_rep=False`` (``check_vma`` on
-    newer jax) is required, and is sound here because every output is
-    ⊕-all-reduced before it leaves the local body.
-    """
-    sm = _resolve_shard_map()
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    except TypeError:  # jax ≥ 0.6 renamed the kwarg
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-
-
 def shard_devices() -> int:
     """Mesh width requested via ``REPRO_SHARD_DEVICES`` (0/1/unset → off)."""
     try:
@@ -81,24 +48,24 @@ def shard_devices() -> int:
 
 
 def make_engine_mesh(devices: int | None = None) -> Mesh | None:
-    """Lazily build the engine's 1-D row-shard mesh, or ``None`` when off.
+    """The engine's 1-D row-shard mesh over the first ``devices`` devices.
 
-    ``devices=None`` reads ``REPRO_SHARD_DEVICES``.  Returns ``None`` (run
-    unsharded) rather than raising when the host cannot provide the
-    devices — CI supplies them via
+    ``devices=None`` reads ``REPRO_SHARD_DEVICES``; a width of 0 or 1 means
+    no mesh (``None``, run unsharded).  Asking for more devices than the
+    backend has raises: a mesh that silently shrank would hide the devices a
+    deployment expects.  CPU hosts provide virtual devices via
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     """
     n = shard_devices() if devices is None else int(devices)
     if n <= 1:
         return None
-    try:
-        if jax.device_count() < n:
-            return None
-    except RuntimeError:  # backend init failed — run unsharded
-        return None
-    from repro.runtime.compat import make_mesh
-
-    return make_mesh((n,), (SHARD_AXIS,))
+    have = jax.device_count()
+    if have < n:
+        raise ValueError(f"a {n}-device mesh needs {n} devices, the backend has {have}")
+    return jax.make_mesh(
+        (n,), (SHARD_AXIS,), axis_types=(jax.sharding.AxisType.Auto,),
+        devices=jax.devices()[:n],
+    )
 
 
 def ring_collective(ring):
@@ -225,7 +192,7 @@ def make_chain_calibrate(mesh: Mesh, axis: str, r: int, d: int, dtype=jnp.float3
 
     shard_spec = P(axis, None)
     msg_spec = P(axis)
-    fn = _resolve_shard_map()(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=([shard_spec] * r,),
@@ -270,7 +237,7 @@ def make_chain_calibrate_multi(mesh: Mesh, axis: str, r: int, d: int,
         return fwd, bwd, totals
 
     msg_spec = P(axis, None)
-    fn = _resolve_shard_map()(
+    fn = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=([P(axis, None)] * r, P(axis, None)),

@@ -22,7 +22,7 @@ it at hardware speed:
   f32 scalar rings (SUM/COUNT via ``kernel_segment_op="sum"``, tropical
   MIN/MAX via ``"min"``/``"max"``) lowers to the ``segment_aggregate`` Pallas
   kernel, and the 2-factor dense contraction of arithmetic rings lowers to
-  the ``semiring_contract`` Pallas kernel (interpret mode off-TPU).  Compound
+  the ``semiring_contract`` Pallas kernel (interpreted off-TPU).  Compound
   rings (MOMENTS, covariance, BOOL, int64 COUNT) keep the lax fallback.
   Off-TPU the one-hot-matmul kernels do O(N·G) work, so they are cost-gated
   (``REPRO_PLAN_KERNEL_COST``): small bags exercise the kernels, huge fact
@@ -330,6 +330,94 @@ class _SparseMeta:
     carried_dims: tuple[int, ...]    # γ-carried dims of the rowwise output
     use_kernel: bool
     cost: int
+    row_bytes: int                   # device bytes the body holds per row
+
+
+# ---------------------------------------------------------------------------
+# row blocks: bound the per-row intermediate of one plan body
+# ---------------------------------------------------------------------------
+
+# A sparse body ⊗-expands every row to its carried γ cells before the
+# segment-⊕, so one call holds rows × cells × leaves values at once: at a
+# fact table of millions of rows and a few hundred carried cells that is
+# gigabytes.  A body whose slab would exceed this many bytes is built for one
+# equal row block and scanned over the blocks, ⊕-combining their partial
+# factors (⊕ is associative and pad rows carry its identity, so any equal
+# split of a power-of-two row bucket is exact).
+ROW_SLAB_BYTES = 1 << 28
+_MIN_BLOCK_ROWS = 2048
+
+# which of a sparse body's (vals, in_fields, in_idx, pred_masks, pred_codes,
+# seg_idx) arguments are row-major; the batched layout has the same prefixes
+_ROW_MAJOR = (True, False, True, False, True, True)
+
+
+def _row_blocks(n: int, row_bytes: int) -> int:
+    """Power-of-two number of equal row blocks keeping a block's slab within
+    :data:`ROW_SLAB_BYTES` (1 = run the rows in one piece)."""
+    blocks = 1
+    while (
+        (n // blocks) * row_bytes > ROW_SLAB_BYTES
+        and n // blocks >= 2 * _MIN_BLOCK_ROWS
+        and (n // blocks) % 2 == 0
+    ):
+        blocks *= 2
+    return blocks
+
+
+def _scan_rows(body: Callable, ring: sr.Semiring, blocks: int,
+               row_major) -> Callable:
+    """Run ``body`` (built for 1/``blocks`` of the rows) over equal row
+    blocks with ``lax.scan``, ⊕-combining its Factor outputs.
+
+    ``row_major`` is a pytree prefix of the arguments marking the row-major
+    ones; the rest are passed whole to every block."""
+    if blocks == 1:
+        return body
+    is_factor = lambda x: isinstance(x, Factor)  # noqa: E731
+
+    def split(rm, sub):
+        if not rm:
+            return ()
+        return jax.tree_util.tree_map(
+            lambda l: l.reshape((blocks, l.shape[0] // blocks) + l.shape[1:]), sub
+        )
+
+    def run(*args):
+        xs = jax.tree_util.tree_map(split, row_major, args)
+
+        def call(block):
+            return body(*jax.tree_util.tree_map(
+                lambda rm, sub, b: b if rm else sub, row_major, args, block
+            ))
+
+        shapes = jax.eval_shape(call, jax.tree_util.tree_map(lambda l: l[0], xs))
+        init = jax.tree_util.tree_map(
+            lambda f: Factor(f.attrs, ring.zeros(f.domain_shape), ring),
+            shapes, is_leaf=is_factor,
+        )
+
+        def step(acc, block):
+            out = call(block)
+            return jax.tree_util.tree_map(
+                lambda a, o: a.add(o), acc, out, is_leaf=is_factor
+            ), None
+
+        return jax.lax.scan(step, init, xs)[0]
+
+    return run
+
+
+def _blocked(build: Callable, n: int, ring: sr.Semiring, row_major):
+    """``build(rows) -> (body, row_bytes, info)`` makes a body over ``rows``
+    rows; returns ``(body over all n rows, info)``, row-blocked when the
+    whole slab would exceed :data:`ROW_SLAB_BYTES`."""
+    body, row_bytes, info = build(n)
+    blocks = _row_blocks(n, row_bytes)
+    if blocks == 1:
+        return body, info
+    body, _, info = build(n // blocks)
+    return _scan_rows(body, ring, blocks, row_major), info
 
 
 def _sparse_plan_parts(
@@ -375,7 +463,6 @@ def _sparse_plan_parts(
         and n > 0
         and (_on_tpu() or cost <= _kernel_cost_max())
     )
-    interpret = not _on_tpu()
     out_shape = tuple(doms[a] for a in local_out)
 
     def rowwise(vals, in_fields, in_idx, pred_masks, pred_codes):
@@ -429,9 +516,7 @@ def _sparse_plan_parts(
             # extra value columns, so count/sum/sumsq share ONE segment pass
             leaves, treedef = jax.tree_util.tree_flatten(vals)
             slab = jnp.concatenate([l.reshape((n, -1)) for l in leaves], axis=1)
-            agg = seg_ops.aggregate_op(
-                seg_idx, slab, total, op=op, interpret=interpret
-            )
+            agg = seg_ops.aggregate_op(seg_idx, slab, total, op=op)
             parts = jnp.split(agg, len(leaves), axis=1) if len(leaves) > 1 else [agg]
             red = [
                 p.reshape((total,) + l.shape[1:]) for p, l in zip(parts, leaves)
@@ -441,13 +526,16 @@ def _sparse_plan_parts(
             field = ring.segment_reduce(vals, seg_idx, total)
         return finalize(field)
 
+    # the rowwise slab, its σ-masked copy and the kernel's row-major copy
+    row_bytes = 3 * vcols * len(ring.trailing) * np.dtype(ring.dtype).itemsize
     meta = _SparseMeta(
-        total=total, carried_dims=carried_dims, use_kernel=use_kernel, cost=cost
+        total=total, carried_dims=carried_dims, use_kernel=use_kernel, cost=cost,
+        row_bytes=row_bytes,
     )
     return fn, rowwise, finalize, meta
 
 
-def _sparse_plan_fn(
+def _sparse_fn(
     ring: sr.Semiring,
     rel_attrs: tuple[str, ...],
     doms: dict[str, int],
@@ -455,11 +543,16 @@ def _sparse_plan_fn(
     pred_attrs: tuple[str, ...],
     out_attrs: tuple[str, ...],
     n: int,
-) -> tuple[Callable, bool]:
-    fn, _, _, meta = _sparse_plan_parts(
-        ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, n
-    )
-    return fn, meta.use_kernel
+) -> tuple[Callable, _SparseMeta]:
+    """The single-contraction body over ``n`` rows, row-blocked if large."""
+
+    def build(rows):
+        fn, _, _, meta = _sparse_plan_parts(
+            ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, rows
+        )
+        return fn, meta.row_bytes, meta
+
+    return _blocked(build, n, ring, _ROW_MAJOR)
 
 
 def _build_sparse_plan(
@@ -471,10 +564,10 @@ def _build_sparse_plan(
     out_attrs: tuple[str, ...],
     n: int,
 ) -> _Plan:
-    fn, use_kernel = _sparse_plan_fn(
+    fn, meta = _sparse_fn(
         ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, n
     )
-    return _Plan(fn=jax.jit(fn), uses_kernel=use_kernel)
+    return _Plan(fn=jax.jit(fn), uses_kernel=meta.use_kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -519,7 +612,7 @@ def _build_sharded_sparse_plan(
     """
     nshards = int(mesh.shape[axis])
     assert n % nshards == 0, f"row bucket {n} not divisible by mesh {nshards}"
-    fn_local, _, _, meta = _sparse_plan_parts(
+    fn_local, meta = _sparse_fn(
         ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs,
         n // nshards,
     )
@@ -530,8 +623,9 @@ def _build_sharded_sparse_plan(
         fact = fn_local(vals, in_fields, in_idx, pred_masks, pred_codes, seg_idx)
         return dist.allreduce_field(fact, collective, axis)
 
-    sm = dist.shard_map_compat(
-        local, mesh, in_specs=_sparse_shard_specs(axis), out_specs=P()
+    sm = jax.shard_map(
+        local, mesh=mesh, in_specs=_sparse_shard_specs(axis), out_specs=P(),
+        check_vma=False,
     )
     return _Plan(
         fn=jax.jit(sm), uses_kernel=meta.use_kernel, sharded=True,
@@ -556,7 +650,7 @@ def _build_sharded_batched_sparse_plan(
     member's sliced output factor is ⊕-all-reduced."""
     nshards = int(mesh.shape[axis])
     assert n % nshards == 0
-    bfn, use_kernel = _batched_sparse_fn(
+    bfn, meta = _batched_sparse_fn(
         ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs,
         n // nshards, member_dims,
     )
@@ -568,14 +662,15 @@ def _build_sharded_batched_sparse_plan(
                     pred_codes, seg_idx)
         return dist.allreduce_field(facts, collective, axis)
 
-    sm = dist.shard_map_compat(
-        local, mesh, in_specs=_sparse_shard_specs(axis), out_specs=P()
+    sm = jax.shard_map(
+        local, mesh=mesh, in_specs=_sparse_shard_specs(axis), out_specs=P(),
+        check_vma=False,
     )
     bytes_ = sum(
         _out_factor_bytes(ring, {**doms, **md}, out_attrs)
         for md in member_dims
     )
-    return _Plan(fn=jax.jit(sm), uses_kernel=use_kernel, sharded=True,
+    return _Plan(fn=jax.jit(sm), uses_kernel=meta.use_kernel, sharded=True,
                  allreduce_bytes=bytes_)
 
 
@@ -739,27 +834,35 @@ def _batched_sparse_fn(
     out_attrs: tuple[str, ...],
     n: int,
     member_dims: tuple[dict[str, int], ...],
-) -> tuple[Callable, bool]:
+) -> tuple[Callable, _SparseMeta]:
     """The raw (un-jitted) B-member batch body: pad + stack + vmap the
-    single-contraction fn, then slice members back out."""
-    fn, use_kernel = _sparse_plan_fn(
-        ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, n
-    )
+    single-contraction fn, then slice members back out (row-blocked if
+    the B members' slabs together are large)."""
     nmembers = len(member_dims)
     stage = _make_batch_stager(ring, set(rel_attrs), doms, in_attrs_list, pred_attrs)
 
-    def bfn(vals_list, in_fields_list, in_idx, pred_masks_list, pred_codes, seg_idx):
-        vals, in_fields, pred_masks = stage(vals_list, in_fields_list, pred_masks_list)
-        batched = jax.vmap(fn, in_axes=(0, 0, None, 0, None, None))(
-            vals, in_fields, in_idx, pred_masks, pred_codes, seg_idx
-        )
-        # slice each member's valid region back out of the padded stack
-        return tuple(
-            _slice_member(ring, batched, member_dims[i], doms, lead=i)
-            for i in range(nmembers)
+    def build(rows):
+        fn, _, _, meta = _sparse_plan_parts(
+            ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, rows
         )
 
-    return bfn, use_kernel
+        def bfn(vals_list, in_fields_list, in_idx, pred_masks_list, pred_codes,
+                seg_idx):
+            vals, in_fields, pred_masks = stage(
+                vals_list, in_fields_list, pred_masks_list
+            )
+            batched = jax.vmap(fn, in_axes=(0, 0, None, 0, None, None))(
+                vals, in_fields, in_idx, pred_masks, pred_codes, seg_idx
+            )
+            # slice each member's valid region back out of the padded stack
+            return tuple(
+                _slice_member(ring, batched, member_dims[i], doms, lead=i)
+                for i in range(nmembers)
+            )
+
+        return bfn, nmembers * meta.row_bytes, meta
+
+    return _blocked(build, n, ring, _ROW_MAJOR)
 
 
 def _build_batched_sparse_plan(
@@ -780,10 +883,10 @@ def _build_batched_sparse_plan(
     per-member output slicing all live inside the traced function, so the
     host dispatches exactly one executable per batch — the whole point.
     """
-    bfn, use_kernel = _batched_sparse_fn(
+    bfn, meta = _batched_sparse_fn(
         ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, n, member_dims
     )
-    return _Plan(fn=jax.jit(bfn), uses_kernel=use_kernel)
+    return _Plan(fn=jax.jit(bfn), uses_kernel=meta.use_kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -804,7 +907,9 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
     kernel-eligible member across ALL groups contributes its
     ``(seg_idx, value slab, num_segments)`` descriptor to a single
     ``level_aggregate`` launch; groups that fail the kernel gate ⊕-reduce on
-    the lax path *inside the same trace*.  Either way the host dispatches one
+    the lax path *inside the same trace*, and so does every group when the
+    fused launch's slab would exceed :data:`ROW_SLAB_BYTES` (each then runs
+    its own row-blocked body).  Either way the host dispatches one
     executable per level, which is the whole point: offline calibration goes
     from one dispatch per batch group to ≤ tree-depth launches.
     """
@@ -812,32 +917,47 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
     for (rel_attrs, doms, in_canon, pred_attrs, out_canon, n, member_dims) in (
         group_statics
     ):
-        fn, rowwise, finalize, meta = _sparse_plan_parts(
+        _, rowwise, finalize, meta = _sparse_plan_parts(
             ring, rel_attrs, doms, in_canon, pred_attrs, out_canon, n
         )
         nmembers = len(member_dims)
-        stage = (
-            _make_batch_stager(ring, set(rel_attrs), doms, in_canon, pred_attrs)
-            if nmembers > 1 else None
-        )
-        bfn = None
-        if nmembers > 1 and not meta.use_kernel:
-            bfn, _ = _batched_sparse_fn(
-                ring, rel_attrs, doms, in_canon, pred_attrs, out_canon, n,
-                member_dims,
-            )
         parts.append({
-            "fn": fn, "rowwise": rowwise, "finalize": finalize, "meta": meta,
-            "stage": stage, "bfn": bfn, "doms": doms,
-            "member_dims": member_dims, "n": n,
+            "rowwise": rowwise, "finalize": finalize, "meta": meta,
+            "stage": (
+                _make_batch_stager(ring, set(rel_attrs), doms, in_canon, pred_attrs)
+                if nmembers > 1 else None
+            ),
+            "statics": (ring, rel_attrs, doms, in_canon, pred_attrs, out_canon, n),
+            "doms": doms, "member_dims": member_dims, "n": n,
         })
-    group_kernel = tuple(p["meta"].use_kernel for p in parts)
-    fused_messages = sum(
-        len(p["member_dims"]) for p in parts if p["meta"].use_kernel
-    )
-    op = ring.kernel_segment_op
-    interpret = not _on_tpu()
+    # the fused launch concatenates every member's slab, padded to the
+    # widest: when that exceeds the row-slab budget, each group runs its own
+    # (row-blocked) body inside this same dispatch instead
+    itemsize = np.dtype(ring.dtype).itemsize
     nleaves = len(ring.trailing)
+    kernel_parts = [p for p in parts if p["meta"].use_kernel]
+    v_max = max(
+        (int(np.prod(p["meta"].carried_dims)) * nleaves for p in kernel_parts),
+        default=0,
+    )
+    fuse = sum(
+        len(p["member_dims"]) * p["n"] * (p["meta"].row_bytes + 2 * v_max * itemsize)
+        for p in kernel_parts
+    ) <= ROW_SLAB_BYTES
+    for p in parts:
+        p["fused"] = fuse and p["meta"].use_kernel
+        if p["fused"]:
+            continue
+        if len(p["member_dims"]) == 1:
+            own, _ = _sparse_fn(*p["statics"])
+            p["own"] = lambda v, f, i, m, c, s, own=own: (
+                own(v[0], f[0], i, m[0], c, s),
+            )
+        else:
+            p["own"], _ = _batched_sparse_fn(*p["statics"], p["member_dims"])
+    group_kernel = tuple(p["meta"].use_kernel for p in parts)
+    fused_messages = sum(len(p["member_dims"]) for p in parts if p["fused"])
+    op = ring.kernel_segment_op
 
     def lfn(groups_args):
         fused_items: list = []
@@ -845,20 +965,11 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
         treedefs: dict = {}
         results: list = [None] * len(parts)
         for g, (part, args) in enumerate(zip(parts, groups_args)):
+            if not part["fused"]:
+                results[g] = part["own"](*args)
+                continue
             vals_list, in_fields_list, in_idx, pred_masks_list, pred_codes, seg_idx = args
             nmembers = len(part["member_dims"])
-            if not part["meta"].use_kernel:
-                if nmembers == 1:
-                    results[g] = (part["fn"](
-                        vals_list[0], in_fields_list[0], in_idx,
-                        pred_masks_list[0], pred_codes, seg_idx,
-                    ),)
-                else:
-                    results[g] = part["bfn"](
-                        vals_list, in_fields_list, in_idx, pred_masks_list,
-                        pred_codes, seg_idx,
-                    )
-                continue
             if nmembers == 1:
                 member_rvs = [part["rowwise"](
                     vals_list[0], in_fields_list[0], in_idx,
@@ -885,9 +996,7 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
                 fused_items.append((seg_idx, slab, part["meta"].total))
                 fused_slots.append((g, b))
         if fused_items:
-            fused_outs = seg_ops.level_aggregate(
-                fused_items, op=op, interpret=interpret
-            )
+            fused_outs = seg_ops.level_aggregate(fused_items, op=op)
             fused_facts: dict = {}
             for (g, b), agg in zip(fused_slots, fused_outs):
                 part = parts[g]
@@ -947,10 +1056,10 @@ def _build_sharded_level_plan(
         return dist.allreduce_field(lfn(groups_args), collective, axis)
 
     per_group = _sparse_shard_specs(axis)
-    sm = dist.shard_map_compat(
-        local, mesh,
+    sm = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(tuple(per_group for _ in group_statics),),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     bytes_ = sum(
         _out_factor_bytes(ring, {**doms, **md}, out_canon)
@@ -1011,7 +1120,6 @@ def _build_dense_plan(
         cand = _matmul_split(structs, out)
         if cand is not None and (_on_tpu() or cand[4] <= _kernel_cost_max()):
             split = cand
-    interpret = not _on_tpu()
 
     def fn(fields, pred_masks):
         factors = [Factor(attrs, f, ring) for (attrs, _), f in zip(structs, fields)]
@@ -1029,14 +1137,12 @@ def _build_dense_plan(
                     g1.field.reshape((f1sz, csz)),
                     g2.field.reshape((csz, f2sz)),
                     is_min=ring.kernel_segment_op == "min",
-                    interpret=interpret,
                 )
             else:
                 o = sc_ops.contract_op(
                     g1.field.reshape((f1sz, csz)),
                     g2.field.reshape((csz, f2sz)),
                     None,
-                    interpret=interpret,
                 )
             field = o.reshape(
                 tuple(doms[a] for a in free1) + tuple(doms[a] for a in free2)
@@ -1080,9 +1186,9 @@ class PlanCache:
         # mesh-sharded execution: with a mesh attached and a ⊕-collective for
         # the ring, sparse/batched/level plans row-shard their bodies under
         # shard_map and ⊕-all-reduce the γ-indexed partials.  Rings without a
-        # collective (BOOL: ⊕ = ∨) and relations whose row bucket does not
-        # divide the mesh silently keep the unsharded plans — sharding is an
-        # execution strategy, never a correctness requirement.
+        # collective (BOOL: ⊕ = ∨) keep the unsharded plans.  Row buckets are
+        # powers of two ≥ 64, so they split over any power-of-two mesh; any
+        # other split raises where the codes are placed (Catalog).
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.shards = int(mesh.shape[mesh_axis]) if mesh is not None else 1
@@ -1127,11 +1233,10 @@ class PlanCache:
             stats.plan_hits += int(not traced)
             stats.kernel_execs += int(entry.uses_kernel)
 
-    def _shard_arity(self, rel) -> int:
-        """Mesh width this relation's plans shard over (1 = unsharded)."""
-        if self._collective is None or rel.row_bucket % self.shards != 0:
-            return 1
-        return self.shards
+    @property
+    def plan_shards(self) -> int:
+        """Mesh width the ring's plans shard over (1 = unsharded)."""
+        return 1 if self._collective is None else self.shards
 
     def _account_sharded(self, entry: _Plan, rels) -> None:
         self.stats.shard_execs += 1
@@ -1168,7 +1273,7 @@ class PlanCache:
         out_attrs: tuple[str, ...],
         stats=None,
     ) -> Factor:
-        shards = self._shard_arity(rel)
+        shards = self.plan_shards
         key = self.sparse_key(rel, vals, incoming, preds, out_attrs)
         if shards > 1:
             key = key + (("shards", shards),)
@@ -1263,13 +1368,8 @@ class PlanCache:
         # order, which σ-variants can permute without changing structure —
         # sort by trace key so every permutation re-hits the same plan
         order = sorted(range(len(specs)), key=lambda i: repr(specs[i].key))
-        # a level shards only when EVERY group's relation divides the mesh —
         # one collective schedule per level, no mixed dispatch
-        shards = self.shards if (
-            self._collective is not None
-            and all(self._shard_arity(s.items[0].rel) == self.shards
-                    for s in specs)
-        ) else 1
+        shards = self.plan_shards
         key = ("level", self.ring.name, tuple(specs[i].key for i in order))
         if shards > 1:
             key = key + (("shards", shards),)
@@ -1294,7 +1394,7 @@ class PlanCache:
         outs = entry.fn(
             tuple(self._group_args(catalog, specs[i]) for i in order)
         )
-        if entry.uses_kernel:
+        if entry.fused_messages:
             self.stats.fused_level_launches += 1
             self.stats.fused_level_messages += entry.fused_messages
         if entry.sharded:
@@ -1430,7 +1530,7 @@ class PlanCache:
         spec = self._group_spec(items, stats_list)
         items, stats_list, inverse = spec.items, spec.stats, spec.inverse
         rel = items[0].rel
-        shards = self._shard_arity(rel)
+        shards = self.plan_shards
         key = spec.key + (("shards", shards),) if shards > 1 else spec.key
         entry = self._plans.get(key)
         traced = entry is None

@@ -1,5 +1,5 @@
-# Pallas TPU kernels for the paper's compute hot-spots (validated with
-# interpret=True on CPU; REPRO_PALLAS=1 or a TPU backend enables compilation):
+# Pallas TPU kernels for the paper's compute hot-spots (compiled by Mosaic on a
+# TPU backend, interpreted elsewhere — see mode.py):
 #   semiring_contract  - MXU-tiled (+,x) message contraction with fused sigma
 #   tropical_contract  - VPU-tiled (min,+)/(max,+) contraction
 #   segment_aggregate  - sparse fact-table rows -> dense factor via one-hot matmul

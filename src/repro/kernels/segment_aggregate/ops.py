@@ -6,10 +6,9 @@ are *shard-local* — inside ``shard_map`` they see the shard's row block
 and produce a full ``(num_segments, v)`` partial that the caller must
 ⊕-all-reduce over the mesh axis (``psum``/``pmin``/``pmax``; see
 ``repro.core.distributed.ring_collective``).  ⊕-identity row padding makes
-any equal block split of a padded row bucket exact, and the Pallas kernels
-require ``check_rep=False`` on the enclosing ``shard_map`` (jax has no
-replication rule for ``pallas_call`` — ``distributed.shard_map_compat``
-handles this).
+any equal block split of a padded row bucket exact, and the enclosing
+``jax.shard_map`` needs ``check_vma=False`` (jax has no replication rule
+for ``pallas_call``).
 """
 
 from __future__ import annotations
@@ -24,33 +23,41 @@ from .kernel import (
     level_segment_aggregate,
     DEFAULT_TG,
     DEFAULT_TN,
+    IDENTITY,
 )
-from .ref import segment_aggregate_ref
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-max(x, 1) // m) * m
+
+
+def _tiles(n: int, g: int) -> tuple[int, int]:
+    """Row tile (a multiple of the 128 lanes) and group tile (of 8 sublanes)."""
+    return min(DEFAULT_TN, _round_up(n, 128)), min(DEFAULT_TG, _round_up(g, 8))
 
 
 @partial(jax.jit, static_argnames=("num_segments", "op", "interpret"))
-def aggregate_op(codes, values, num_segments: int, op: str = "sum", interpret: bool = True):
+def aggregate_op(codes, values, num_segments: int, op: str = "sum",
+                 interpret: bool | None = None):
+    """(G, V) ⊕-aggregate of an (N, V) slab (or (G,) of an (N,) vector)."""
     n = codes.shape[0]
     squeeze = values.ndim == 1
     if squeeze:
         values = values[:, None]
-    v = values.shape[1]
-    tn = min(DEFAULT_TN, max(8, n))
-    tg = min(DEFAULT_TG, max(8, num_segments))
+    tn, tg = _tiles(n, num_segments)
     pad_n = (-n) % tn
     pad_g = (-num_segments) % tg
-    if pad_n:
-        # padded rows carry the ⊕-identity so they are no-ops in any group
-        ident = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}[op]
-        codes = jnp.concatenate([codes, jnp.full((pad_n,), num_segments + pad_g - 1, codes.dtype)])
-        values = jnp.concatenate([values, jnp.full((pad_n, v), ident, values.dtype)])
+    # pad rows carry code -1, which matches no segment
+    codes = jnp.pad(codes.astype(jnp.int32), (0, pad_n), constant_values=-1)
+    values = jnp.pad(values.astype(jnp.float32), ((0, pad_n), (0, 0)))
     out = segment_aggregate(
-        codes, values, num_segments + pad_g, op=op, tn=tn, tg=tg, interpret=interpret
+        codes[None, :], values.T, num_segments + pad_g, op=op, tn=tn, tg=tg,
+        interpret=interpret,
     )[:num_segments]
     return out[:, 0] if squeeze else out
 
 
-def level_aggregate(items, op: str = "sum", interpret: bool = True):
+def level_aggregate(items, op: str = "sum", interpret: bool | None = None):
     """Fuse several independent ``(codes, values, num_segments)`` segment
     reductions into ONE ``level_segment_aggregate`` launch.
 
@@ -65,10 +72,10 @@ def level_aggregate(items, op: str = "sum", interpret: bool = True):
     eager calls work too via pallas interpret mode.
     """
     assert items, "level_aggregate of zero messages"
-    ident = {"sum": 0.0, "min": jnp.inf, "max": -jnp.inf}[op]
+    ident = IDENTITY[op]
     v_max = max(v.shape[1] for _, v, _ in items)
-    tn = min(DEFAULT_TN, max(8, max(c.shape[0] for c, _, _ in items)))
-    tg = min(DEFAULT_TG, max(8, max(g for _, _, g in items)))
+    tn, tg = _tiles(max(c.shape[0] for c, _, _ in items),
+                    max(g for _, _, g in items))
     all_codes, all_vals = [], []
     row_blocks, seg_blocks, tile_start, tile_first = [], [], [], []
     row_off = seg_off = 0
@@ -87,7 +94,7 @@ def level_aggregate(items, op: str = "sum", interpret: bool = True):
                 constant_values=ident,
             )
         all_codes.append(codes)
-        all_vals.append(values.astype(jnp.float32))
+        all_vals.append(values.astype(jnp.float32).T)
         n_blocks = (n + pad_n) // tn
         g_blocks = (g + pad_g) // tg
         for s in range(g_blocks):
@@ -100,8 +107,8 @@ def level_aggregate(items, op: str = "sum", interpret: bool = True):
         row_off += n + pad_n
         seg_off += g + pad_g
     out = level_segment_aggregate(
-        jnp.concatenate(all_codes),
-        jnp.concatenate(all_vals),
+        jnp.concatenate(all_codes)[None, :],
+        jnp.concatenate(all_vals, axis=1),
         jnp.asarray(row_blocks, jnp.int32),
         jnp.asarray(seg_blocks, jnp.int32),
         jnp.asarray(tile_start, jnp.int32),
@@ -117,9 +124,3 @@ def level_aggregate(items, op: str = "sum", interpret: bool = True):
         for (off, g), (_, v, _) in zip(spans, items)
     ]
 
-
-def aggregate(codes, values, num_segments, op="sum", use_kernel=True):
-    if use_kernel:
-        return aggregate_op(codes, values, num_segments, op=op,
-                            interpret=jax.default_backend() != "tpu")
-    return segment_aggregate_ref(codes, values, num_segments, op)

@@ -11,11 +11,11 @@ each output tile is initialized at b==0 and accumulated across b steps.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import mode
 
 
 DEFAULT_TILES = (128, 128, 128)  # (TG, TB, TA) — MXU-aligned
@@ -28,17 +28,18 @@ def _kernel(m_ref, r_ref, mask_ref, o_ref):
 
     m = m_ref[...].astype(jnp.float32)
     if mask_ref is not None:
-        m = m * mask_ref[...].astype(jnp.float32)[None, :]
+        m = m * mask_ref[...].astype(jnp.float32)     # (1, TB) row mask
     r = r_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(m, r, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(m, r, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
 
 
 def semiring_contract(
     m: jax.Array,                  # (G, B)
     r: jax.Array,                  # (B, A)
-    mask: jax.Array | None = None,  # (B,) 0/1 σ mask on the contracted axis
+    mask: jax.Array | None = None,  # (1, B) 0/1 σ mask on the contracted axis
     tiles: tuple[int, int, int] = DEFAULT_TILES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     g, b = m.shape
     b2, a = r.shape
@@ -53,21 +54,18 @@ def semiring_contract(
     ]
     args = [m, r]
     if mask is not None:
-        in_specs.append(pl.BlockSpec((tb,), lambda i, j, k: (k,)))
+        assert mask.shape == (1, b), (mask.shape, m.shape)
+        in_specs.append(pl.BlockSpec((1, tb), lambda i, j, k: (0, k)))
         args.append(mask)
-        kern = _kernel
-    else:
-        kern = functools.partial(_masked_none_kernel)
-
-    out = pl.pallas_call(
-        kern if mask is not None else _masked_none_kernel,
+    return pl.pallas_call(
+        _kernel if mask is not None else _masked_none_kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tg, ta), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((g, a), jnp.float32),
-        interpret=interpret,
+        interpret=mode.resolve_interpret(interpret),
+        name="semiring_contract",
     )(*args)
-    return out
 
 
 def _masked_none_kernel(m_ref, r_ref, o_ref):

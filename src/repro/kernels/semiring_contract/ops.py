@@ -1,23 +1,17 @@
-"""Jit'd wrapper with shape padding; selects the Pallas kernel or the jnp ref.
+"""Jit'd wrapper for semiring_contract with shape padding.
 
-On CPU (this container) the kernel runs in ``interpret=True`` mode for
-correctness validation; on a TPU build set ``REPRO_PALLAS=1`` to compile it.
+The kernel compiles on a TPU backend and runs interpreted elsewhere
+(``repro.kernels.mode``).
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from .kernel import semiring_contract, DEFAULT_TILES
-from .ref import semiring_contract_ref
-
-
-def use_pallas() -> bool:
-    return os.environ.get("REPRO_PALLAS", "0") == "1" or jax.default_backend() == "tpu"
 
 
 def _pad_to(x, mult, axis):
@@ -30,7 +24,7 @@ def _pad_to(x, mult, axis):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def contract_op(m, r, mask=None, interpret: bool = True):
+def contract_op(m, r, mask=None, interpret: bool | None = None):
     """Padded semiring contraction; returns (G, A) fp32."""
     g, b = m.shape
     a = r.shape[1]
@@ -43,12 +37,7 @@ def contract_op(m, r, mask=None, interpret: bool = True):
     rp, _ = _pad_to(rp, ta, 1)
     mk = None
     if mask is not None:
-        mk, _ = _pad_to(mask.astype(jnp.float32), tb, 0)
+        mk, _ = _pad_to(mask.astype(jnp.float32)[None, :], tb, 1)
     out = semiring_contract(mp, rp, mk, tiles=(tg, tb, ta), interpret=interpret)
     return out[:g, :a]
 
-
-def contract(m, r, mask=None):
-    if use_pallas():
-        return contract_op(m, r, mask, interpret=jax.default_backend() != "tpu")
-    return semiring_contract_ref(m, r, mask)

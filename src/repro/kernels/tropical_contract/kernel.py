@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mode
+
 DEFAULT_TILES = (16, 128, 128)  # (TG, TB, TA): (16·128·128)·4B = 1 MiB slab
 
 
@@ -39,7 +41,7 @@ def tropical_contract(
     r: jax.Array,                   # (B, A)
     is_min: bool = True,
     tiles: tuple[int, int, int] = DEFAULT_TILES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     g, b = m.shape
     b2, a = r.shape
@@ -56,5 +58,6 @@ def tropical_contract(
         ],
         out_specs=pl.BlockSpec((tg, ta), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((g, a), jnp.float32),
-        interpret=interpret,
+        interpret=mode.resolve_interpret(interpret),
+        name="tropical_contract",
     )(m, r)

@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 
 from .kernel import tropical_contract, DEFAULT_TILES
-from .ref import tropical_contract_ref
 
 
 def _pad_to(x, mult, axis, value):
@@ -21,7 +20,7 @@ def _pad_to(x, mult, axis, value):
 
 
 @partial(jax.jit, static_argnames=("is_min", "interpret"))
-def contract_op(m, r, is_min: bool = True, interpret: bool = True):
+def contract_op(m, r, is_min: bool = True, interpret: bool | None = None):
     g, b = m.shape
     a = r.shape[1]
     ident = jnp.inf if is_min else -jnp.inf  # ⊕-identity pads the contracted axis
@@ -34,8 +33,3 @@ def contract_op(m, r, is_min: bool = True, interpret: bool = True):
     out = tropical_contract(mp, rp, is_min=is_min, tiles=(tg, tb, ta), interpret=interpret)
     return out[:g, :a]
 
-
-def contract(m, r, is_min=True, use_kernel=True):
-    if use_kernel:
-        return contract_op(m, r, is_min=is_min, interpret=jax.default_backend() != "tpu")
-    return tropical_contract_ref(m, r, is_min)

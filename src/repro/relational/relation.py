@@ -501,10 +501,16 @@ class Catalog:
             pad = rel.row_bucket - idx.size
             if pad > 0:
                 idx = np.concatenate([idx, np.zeros((pad,), idx.dtype)])
-            arr = jnp.asarray(idx.astype(np.int32))
-            if self._row_placement is not None and (
-                arr.shape[0] % getattr(self._row_placement.mesh, "size", 1) == 0
-            ):
+            arr = idx.astype(np.int32)
+            if self._row_placement is None:
+                arr = jnp.asarray(arr)
+            else:
+                shards = self._row_placement.mesh.size
+                if arr.shape[0] % shards:
+                    raise ValueError(
+                        f"{rel.name}: row bucket {arr.shape[0]} does not split "
+                        f"over a {shards}-device mesh"
+                    )
                 arr = jax.device_put(arr, self._row_placement)
             hit = (arr, total)
             self._dev_codes.put(key, hit)

@@ -1,14 +1,8 @@
-"""Version-compat shims over jax API drift.
+"""Small helpers over the installed jax's mesh and cost-analysis API.
 
-Two call sites in this repo broke across jax releases:
-
-- ``jax.sharding.AxisType`` (and ``jax.make_mesh(..., axis_types=...)``
-  accepting it) only exists in newer jax; older releases auto-type every
-  axis.  ``make_mesh`` requests Auto axes when the enum exists and silently
-  gets the same behavior when it doesn't.
-- ``Compiled.cost_analysis()`` returned a one-element list of dicts in older
-  jax and a plain dict in newer.  ``cost_analysis``/``compiled_flops``
-  normalize to a dict.
+- ``make_mesh`` builds a mesh whose axes are all Auto (or all Explicit).
+- ``cost_analysis``/``compiled_flops`` read ``Compiled.cost_analysis()``,
+  a dict of XLA's cost estimates, as a plain (possibly empty) dict.
 """
 
 from __future__ import annotations
@@ -20,22 +14,15 @@ import jax
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               explicit: bool = False) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto (or Explicit) axis types where supported."""
-    axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
-    kind = axis_type.Explicit if explicit else axis_type.Auto
-    return jax.make_mesh(axis_shapes, axis_names, axis_types=(kind,) * len(axis_names))
+    """``jax.make_mesh`` with every axis Auto (or Explicit)."""
+    kind = jax.sharding.AxisType.Explicit if explicit else jax.sharding.AxisType.Auto
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(kind,) * len(axis_names))
 
 
 def cost_analysis(compiled) -> Mapping[str, float]:
-    """Normalized ``Compiled.cost_analysis()``: always a (possibly empty) dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def compiled_flops(compiled) -> float:

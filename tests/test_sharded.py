@@ -6,8 +6,7 @@ device mesh and ⊕-all-reducing the γ-indexed partials must be **invisible**
 bit-identical to a single-device engine across rings (SUM/COUNT/MIN/MAX;
 MOMENTS under allclose), join shapes (chain/star/bushy), plans on/off and
 mesh widths 1/2/8.  Sharding is an execution strategy, never a semantic:
-rings without a collective (BOOL) silently run unsharded, relations whose
-row bucket does not divide the mesh fall back per-dispatch, and deltas
+rings without a collective (BOOL) run unsharded, and deltas
 (``apply_delta`` / ``stream().flush()``) shard the same way the base scan
 does.
 
@@ -265,6 +264,7 @@ def test_make_engine_mesh_disabled(monkeypatch):
     assert dist.shard_devices() == 0
     monkeypatch.setenv("REPRO_SHARD_DEVICES", "8")
     assert dist.shard_devices() == 8
-    # more shards than devices: sharding silently disables (never an error)
+    # more shards than devices: an error, never a silently smaller mesh
     monkeypatch.setenv("REPRO_SHARD_DEVICES", str(jax.device_count() * 1000))
-    assert dist.make_engine_mesh() is None
+    with pytest.raises(ValueError, match="devices"):
+        dist.make_engine_mesh()
