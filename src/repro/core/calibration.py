@@ -70,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.relational.relation import LRU, Catalog, Delta, Predicate, Relation, lift_rows
+from repro.trace import span
 from . import distributed as dist
 from . import semiring as sr
 from .factor import Factor, contract, ones_factor
@@ -735,7 +736,10 @@ class CJTEngine:
     def absorb(self, q: Query, root: str, placement=None, stats=None, keep=None) -> Factor:
         """Absorption at root (§3.3.1) then projection to γ (or ``keep``)."""
         placement = self.place_predicates(q) if placement is None else placement
-        incoming = [self.message(q, i, root, placement, stats) for i in self.jt.neighbors(root)]
+        with span("treant.engine.messages"):
+            incoming = [
+                self.message(q, i, root, placement, stats) for i in self.jt.neighbors(root)
+            ]
         keep = tuple(keep) if keep is not None else q.group_by
         avail = set(self.jt.subtree_attrs(root, None))
         out_attrs = tuple(a for a in dict.fromkeys(keep) if a in avail)
@@ -1010,18 +1014,19 @@ class CJTEngine:
         plan inputs are device-resident); ``sync=True`` blocks once on the
         absorbed result so callers observe completed work.
         """
-        stats = ExecStats()
-        placement = self.place_predicates(q)
-        root = root or self.choose_root(q, placement)
-        with self.store.inflight():
-            f = self.absorb(q, root, placement, stats)
-        out = f.project_to(q.group_by)
-        # the cache misses ARE the Steiner tree (§3.4.2): report its realized
-        # size directly instead of planning it a second time (Treant used to)
-        touched = {b for edge in stats.recomputed_edges for b in edge}
-        stats.steiner_size = len(touched | {root})
-        if sync:
-            jax.block_until_ready(out.field)
+        with span("treant.engine.execute", queries=1):
+            stats = ExecStats()
+            placement = self.place_predicates(q)
+            root = root or self.choose_root(q, placement)
+            with self.store.inflight():
+                f = self.absorb(q, root, placement, stats)
+            out = f.project_to(q.group_by)
+            # the cache misses ARE the Steiner tree (§3.4.2): report its realized
+            # size directly instead of planning it a second time (Treant used to)
+            touched = {b for edge in stats.recomputed_edges for b in edge}
+            stats.steiner_size = len(touched | {root})
+            if sync:
+                jax.block_until_ready(out.field)
         return out, stats
 
     def execute_many(
@@ -1047,7 +1052,7 @@ class CJTEngine:
         (``tests/test_batched_plans.py``).  Dense/densified bags and
         ``use_plans=False`` engines simply fall back to per-query absorption.
         """
-        with self.store.inflight():
+        with span("treant.engine.execute", queries=len(queries)), self.store.inflight():
             return self._execute_many_inflight(queries, sync, tags)
 
     def _execute_many_inflight(
@@ -1060,44 +1065,48 @@ class CJTEngine:
         all_stats: list[ExecStats] = []
         roots: list[str] = []
         deferred: list[tuple[int, AbsorbItem]] = []
-        for i, q in enumerate(queries):
-            stats = ExecStats()
-            all_stats.append(stats)
-            placement = self.place_predicates(q)
-            root = self.choose_root(q, placement)
-            roots.append(root)
-            old_tag = self.store.tag
-            if tags is not None and tags[i] is not None:
-                self.store.tag = tags[i]
-            try:
-                incoming = [
-                    self.message(q, u, root, placement, stats)
-                    for u in self.jt.neighbors(root)
-                ]
-            finally:
-                self.store.tag = old_tag
-            keep = tuple(q.group_by)
-            avail = set(self.jt.subtree_attrs(root, None))
-            out_attrs = tuple(a for a in dict.fromkeys(keep) if a in avail)
-            rel_names = [r for r in self.jt.relations_of(root) if r not in q.removed]
-            rels = [self.catalog.get(r, q.version_of(r)) for r in rel_names]
-            sparse = (
-                len(rels) == 1
-                and rels[0].num_rows > self.dense_rows_threshold
-                and self.plans is not None
-                and len(queries) > 1
-            )
-            if sparse:
-                stats.rows_scanned += rels[0].num_rows
-                deferred.append((i, AbsorbItem(
-                    rel=rels[0], vals=self._lift(q, rels[0]),
-                    incoming=tuple(incoming),
-                    preds=placement.get(root, ()), out_attrs=out_attrs,
-                )))
-            else:
-                results[i] = self._bag_contract(
-                    q, root, incoming, out_attrs, placement, stats
+        # every query's message passing (store probes included) and the
+        # inputs of its absorption, in one span: a span per query would be
+        # a hundred per step of a busy server
+        with span("treant.engine.messages", queries=len(queries)):
+            for i, q in enumerate(queries):
+                stats = ExecStats()
+                all_stats.append(stats)
+                placement = self.place_predicates(q)
+                root = self.choose_root(q, placement)
+                roots.append(root)
+                old_tag = self.store.tag
+                if tags is not None and tags[i] is not None:
+                    self.store.tag = tags[i]
+                try:
+                    incoming = [
+                        self.message(q, u, root, placement, stats)
+                        for u in self.jt.neighbors(root)
+                    ]
+                finally:
+                    self.store.tag = old_tag
+                keep = tuple(q.group_by)
+                avail = set(self.jt.subtree_attrs(root, None))
+                out_attrs = tuple(a for a in dict.fromkeys(keep) if a in avail)
+                rel_names = [r for r in self.jt.relations_of(root) if r not in q.removed]
+                rels = [self.catalog.get(r, q.version_of(r)) for r in rel_names]
+                sparse = (
+                    len(rels) == 1
+                    and rels[0].num_rows > self.dense_rows_threshold
+                    and self.plans is not None
+                    and len(queries) > 1
                 )
+                if sparse:
+                    stats.rows_scanned += rels[0].num_rows
+                    deferred.append((i, AbsorbItem(
+                        rel=rels[0], vals=self._lift(q, rels[0]),
+                        incoming=tuple(incoming),
+                        preds=placement.get(root, ()), out_attrs=out_attrs,
+                    )))
+                else:
+                    results[i] = self._bag_contract(
+                        q, root, incoming, out_attrs, placement, stats
+                    )
         groups: dict[tuple, list[tuple[int, AbsorbItem]]] = {}
         for i, item in deferred:
             groups.setdefault(absorb_batch_key(self.ring, item), []).append((i, item))
@@ -1127,12 +1136,6 @@ class CJTEngine:
                     }
                     for i, _ in members:
                         all_stats[i].batch_sessions = len(owners)
-                    if self.plans is not None and len(owners) > 1:
-                        ps = self.plans.stats
-                        ps.cross_session_execs += 1
-                        ps.cross_session_width = max(
-                            ps.cross_session_width, len(owners)
-                        )
         outs: list[tuple[Factor, ExecStats]] = []
         for i, q in enumerate(queries):
             out = results[i].project_to(q.group_by)
@@ -1322,7 +1325,7 @@ class CJTEngine:
         number of edges advanced; a partially-stepped level (``plan.offset``)
         is finished first.
         """
-        with self.store.inflight():
+        with span("treant.engine.calibrate_level", plans=len(plans)), self.store.inflight():
             return self._run_level_inflight(plans, stats_list, tags)
 
     def _run_level_inflight(

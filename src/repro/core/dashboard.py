@@ -70,6 +70,7 @@ from typing import TYPE_CHECKING, Mapping
 import jax
 
 from repro.relational.relation import Predicate, mask_in, mask_range
+from repro.trace import span
 from .calibration import CalibrationPlan, CJTEngine, ExecStats, factor_nbytes
 from .plans import slice_bin_cube, slice_bin_cubes
 from .predictive import (
@@ -800,17 +801,18 @@ class Session:
         is full of revisited states (backtracks, jump-and-return, Undo), and
         a replayed state reuses the frozen Query objects outright instead of
         re-running the per-viz predicate placement."""
-        token = self._derive_token()
-        derived = self._derive_memo.get(token)
-        if derived is None:
-            derived = {name: self.derive(name) for name in sorted(self._views)}
-            if len(self._derive_memo) > 512:
-                self._derive_memo.clear()
-            self._derive_memo[token] = derived
-        affected = tuple(
-            name for name, q in derived.items()
-            if q.digest != self._current[name].digest
-        )
+        with span("treant.session.derive"):
+            token = self._derive_token()
+            derived = self._derive_memo.get(token)
+            if derived is None:
+                derived = {name: self.derive(name) for name in sorted(self._views)}
+                if len(self._derive_memo) > 512:
+                    self._derive_memo.clear()
+                self._derive_memo[token] = derived
+            affected = tuple(
+                name for name, q in derived.items()
+                if q.digest != self._current[name].digest
+            )
         return dict(derived), affected
 
     def _mutate(self, event) -> None:

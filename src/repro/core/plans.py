@@ -46,6 +46,7 @@ it at hardware speed:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -61,6 +62,7 @@ from repro.kernels.segment_aggregate import ops as seg_ops
 from repro.kernels.semiring_contract import ops as sc_ops
 from repro.kernels.tropical_contract import ops as tc_ops
 from repro.relational.relation import LRU, Predicate
+from repro.trace import span
 
 from . import distributed as dist
 from . import semiring as sr
@@ -196,10 +198,6 @@ class PlanStats:
     # calibration level ⊕-reduced by ONE multi-segment Pallas launch
     fused_level_launches: int = 0    # fused level launches dispatched
     fused_level_messages: int = 0    # messages served by those launches
-    # cross-session batched fan-out (TreantServer): vmapped dispatches whose
-    # members span >1 session, and the widest distinct-session count observed
-    cross_session_execs: int = 0
-    cross_session_width: int = 0
     # mesh-sharded execution (PlanCache(mesh=...)): dispatches that ran under
     # shard_map, the bytes their ⊕-all-reduce collectives carried (static per
     # plan: Σ output-factor payloads), and the worst row imbalance observed
@@ -215,10 +213,7 @@ class PlanStats:
 
     # counters that are high-water marks, not sums: cross-engine aggregation
     # (Treant.cache_stats) takes max for these and Σ for everything else
-    MAX_FIELDS = (
-        "batch_width", "level_batch_width", "cross_session_width",
-        "shard_imbalance",
-    )
+    MAX_FIELDS = ("batch_width", "level_batch_width", "shard_imbalance")
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -231,13 +226,14 @@ def _compiled_slice(dim: str, group_by: tuple[str, ...]):
     warm brush costs a single compiled dispatch instead of one eager op per
     σ mask plus the marginalization."""
 
-    def run(cube, masks):
-        f = cube
-        for m in masks:
-            f = f.select(dim, m)
-        return f.project_to(group_by)
+    def cube_slice(cube, masks):
+        with jax.named_scope("cube_slice"):
+            f = cube
+            for m in masks:
+                f = f.select(dim, m)
+            return f.project_to(group_by)
 
-    return jax.jit(run)
+    return jax.jit(cube_slice)
 
 
 @functools.lru_cache(maxsize=1024)
@@ -276,16 +272,17 @@ def _compiled_slice_batch(spec: tuple):
     A 7-viz crossfilter brush costs ONE compiled dispatch instead of seven —
     the cube analog of ``batch_fanout``'s vmapped absorption groups."""
 
-    def run(cubes, masks_list):
+    def cube_slice_batch(cubes, masks_list):
         outs = []
-        for (dim, group_by), cube, masks in zip(spec, cubes, masks_list):
-            f = cube
-            for m in masks:
-                f = f.select(dim, m)
-            outs.append(f.project_to(group_by))
+        with jax.named_scope("cube_slice"):
+            for (dim, group_by), cube, masks in zip(spec, cubes, masks_list):
+                f = cube
+                for m in masks:
+                    f = f.select(dim, m)
+                outs.append(f.project_to(group_by))
         return tuple(outs)
 
-    return jax.jit(run)
+    return jax.jit(cube_slice_batch)
 
 
 def slice_bin_cubes(items, stats: PlanStats | None = None) -> list:
@@ -384,26 +381,29 @@ def _scan_rows(body: Callable, ring: sr.Semiring, blocks: int,
         )
 
     def run(*args):
-        xs = jax.tree_util.tree_map(split, row_major, args)
+        # the split into blocks and the ⊕-combine of their partial factors;
+        # the body's own stages keep their inner scopes
+        with jax.named_scope("row_blocks"):
+            xs = jax.tree_util.tree_map(split, row_major, args)
 
-        def call(block):
-            return body(*jax.tree_util.tree_map(
-                lambda rm, sub, b: b if rm else sub, row_major, args, block
-            ))
+            def call(block):
+                return body(*jax.tree_util.tree_map(
+                    lambda rm, sub, b: b if rm else sub, row_major, args, block
+                ))
 
-        shapes = jax.eval_shape(call, jax.tree_util.tree_map(lambda l: l[0], xs))
-        init = jax.tree_util.tree_map(
-            lambda f: Factor(f.attrs, ring.zeros(f.domain_shape), ring),
-            shapes, is_leaf=is_factor,
-        )
+            shapes = jax.eval_shape(call, jax.tree_util.tree_map(lambda l: l[0], xs))
+            init = jax.tree_util.tree_map(
+                lambda f: Factor(f.attrs, ring.zeros(f.domain_shape), ring),
+                shapes, is_leaf=is_factor,
+            )
 
-        def step(acc, block):
-            out = call(block)
-            return jax.tree_util.tree_map(
-                lambda a, o: a.add(o), acc, out, is_leaf=is_factor
-            ), None
+            def step(acc, block):
+                out = call(block)
+                return jax.tree_util.tree_map(
+                    lambda a, o: a.add(o), acc, out, is_leaf=is_factor
+                ), None
 
-        return jax.lax.scan(step, init, xs)[0]
+            return jax.lax.scan(step, init, xs)[0]
 
     return run
 
@@ -466,64 +466,67 @@ def _sparse_plan_parts(
     out_shape = tuple(doms[a] for a in local_out)
 
     def rowwise(vals, in_fields, in_idx, pred_masks, pred_codes):
-        for (m_attrs, shared, extra, have, want), field, idx in zip(
-            steps, in_fields, in_idx
-        ):
-            mp = Factor(m_attrs, field, ring).project_to(shared + extra)
-            dims = [doms[a] for a in shared]
+        with jax.named_scope("rowwise"):
+            for (m_attrs, shared, extra, have, want), field, idx in zip(
+                steps, in_fields, in_idx
+            ):
+                mp = Factor(m_attrs, field, ring).project_to(shared + extra)
+                dims = [doms[a] for a in shared]
 
-            def gather(leaf):
-                lead = leaf.reshape(
-                    (int(np.prod(dims)) if shared else 1,) + leaf.shape[len(shared):]
+                def gather(leaf):
+                    lead = leaf.reshape(
+                        (int(np.prod(dims)) if shared else 1,) + leaf.shape[len(shared):]
+                    )
+                    if shared:
+                        return jnp.take(lead, idx, axis=0)
+                    return jnp.broadcast_to(lead, (n,) + lead.shape[1:])
+
+                leaves, treedef = jax.tree_util.tree_flatten(mp.field)
+                g = jax.tree_util.tree_unflatten(treedef, [gather(l) for l in leaves])
+                vals = ring.mul(
+                    expand_rows_field(vals, have, want, ring.trailing),
+                    expand_rows_field(g, extra, want, ring.trailing),
                 )
-                if shared:
-                    return jnp.take(lead, idx, axis=0)
-                return jnp.broadcast_to(lead, (n,) + lead.shape[1:])
-
-            leaves, treedef = jax.tree_util.tree_flatten(mp.field)
-            g = jax.tree_util.tree_unflatten(treedef, [gather(l) for l in leaves])
-            vals = ring.mul(
-                expand_rows_field(vals, have, want, ring.trailing),
-                expand_rows_field(g, extra, want, ring.trailing),
-            )
-        if pred_attrs:
-            # σ as a rowwise ⊗ with 0̄/1̄: gather each domain mask at the row
-            # codes on-device (the mask *content* is a traced arg, so new
-            # selections re-execute the same compiled plan)
-            rowm = pred_masks[0][pred_codes[0]]
-            for mask, codes in zip(pred_masks[1:], pred_codes[1:]):
-                rowm = rowm & mask[codes]
-            zeros = ring.zeros((n,) + carried_dims)
-            leaves, treedef = jax.tree_util.tree_flatten(vals)
-            zleaves = jax.tree_util.tree_leaves(zeros)
-            out = []
-            for leaf, z in zip(leaves, zleaves):
-                m = rowm.reshape((n,) + (1,) * (leaf.ndim - 1))
-                out.append(jnp.where(m, leaf, z))
-            vals = jax.tree_util.tree_unflatten(treedef, out)
-        return vals
+            if pred_attrs:
+                # σ as a rowwise ⊗ with 0̄/1̄: gather each domain mask at the row
+                # codes on-device (the mask *content* is a traced arg, so new
+                # selections re-execute the same compiled plan)
+                rowm = pred_masks[0][pred_codes[0]]
+                for mask, codes in zip(pred_masks[1:], pred_codes[1:]):
+                    rowm = rowm & mask[codes]
+                zeros = ring.zeros((n,) + carried_dims)
+                leaves, treedef = jax.tree_util.tree_flatten(vals)
+                zleaves = jax.tree_util.tree_leaves(zeros)
+                out = []
+                for leaf, z in zip(leaves, zleaves):
+                    m = rowm.reshape((n,) + (1,) * (leaf.ndim - 1))
+                    out.append(jnp.where(m, leaf, z))
+                vals = jax.tree_util.tree_unflatten(treedef, out)
+            return vals
 
     def finalize(field):
-        field = jax.tree_util.tree_map(
-            lambda l: l.reshape(out_shape + l.shape[1:]), field
-        )
-        return Factor(local_out + carried, field, ring).project_to(out_attrs)
+        with jax.named_scope("finalize"):
+            field = jax.tree_util.tree_map(
+                lambda l: l.reshape(out_shape + l.shape[1:]), field
+            )
+            return Factor(local_out + carried, field, ring).project_to(out_attrs)
 
     def fn(vals, in_fields, in_idx, pred_masks, pred_codes, seg_idx):
         vals = rowwise(vals, in_fields, in_idx, pred_masks, pred_codes)
-        if use_kernel:
-            # compound rings (MOMENTS) stack their equal-shape leaves as
-            # extra value columns, so count/sum/sumsq share ONE segment pass
-            leaves, treedef = jax.tree_util.tree_flatten(vals)
-            slab = jnp.concatenate([l.reshape((n, -1)) for l in leaves], axis=1)
-            agg = seg_ops.aggregate_op(seg_idx, slab, total, op=op)
-            parts = jnp.split(agg, len(leaves), axis=1) if len(leaves) > 1 else [agg]
-            red = [
-                p.reshape((total,) + l.shape[1:]) for p, l in zip(parts, leaves)
-            ]
-            field = jax.tree_util.tree_unflatten(treedef, red)
-        else:
-            field = ring.segment_reduce(vals, seg_idx, total)
+        with jax.named_scope(_reduce_scope(ring)):
+            if use_kernel:
+                # compound rings (MOMENTS) stack their equal-shape leaves as
+                # extra value columns, so count/sum/sumsq share ONE segment pass
+                leaves, treedef = jax.tree_util.tree_flatten(vals)
+                slab = jnp.concatenate([l.reshape((n, -1)) for l in leaves], axis=1)
+                agg = seg_ops.aggregate_op(seg_idx, slab, total, op=op)
+                parts = jnp.split(agg, len(leaves), axis=1) if len(leaves) > 1 else [agg]
+                red = [
+                    p.reshape((total,) + l.shape[1:]) for p, l in zip(parts, leaves)
+                ]
+                field = jax.tree_util.tree_unflatten(treedef, red)
+            else:
+                field = ring.segment_reduce(vals, seg_idx, total)
         return finalize(field)
 
     # the rowwise slab, its σ-masked copy and the kernel's row-major copy
@@ -533,6 +536,12 @@ def _sparse_plan_parts(
         row_bytes=row_bytes,
     )
     return fn, rowwise, finalize, meta
+
+
+def _reduce_scope(ring: sr.Semiring) -> str:
+    """The device scope of a ring's segment reductions, named for its ⊕:
+    the kernel's op (``sum``, ``min``, ``max``), else the ring's name."""
+    return f"segment_reduce_{ring.kernel_segment_op or ring.name}"
 
 
 def _sparse_fn(
@@ -567,7 +576,11 @@ def _build_sparse_plan(
     fn, meta = _sparse_fn(
         ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, n
     )
-    return _Plan(fn=jax.jit(fn), uses_kernel=meta.use_kernel)
+
+    def sparse_plan(*args):
+        return fn(*args)
+
+    return _Plan(fn=jax.jit(sparse_plan), uses_kernel=meta.use_kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -627,8 +640,12 @@ def _build_sharded_sparse_plan(
         local, mesh=mesh, in_specs=_sparse_shard_specs(axis), out_specs=P(),
         check_vma=False,
     )
+
+    def sharded_sparse_plan(*args):
+        return sm(*args)
+
     return _Plan(
-        fn=jax.jit(sm), uses_kernel=meta.use_kernel, sharded=True,
+        fn=jax.jit(sharded_sparse_plan), uses_kernel=meta.use_kernel, sharded=True,
         allreduce_bytes=_out_factor_bytes(ring, doms, out_attrs),
     )
 
@@ -670,8 +687,12 @@ def _build_sharded_batched_sparse_plan(
         _out_factor_bytes(ring, {**doms, **md}, out_attrs)
         for md in member_dims
     )
-    return _Plan(fn=jax.jit(sm), uses_kernel=meta.use_kernel, sharded=True,
-                 allreduce_bytes=bytes_)
+
+    def sharded_sparse_batch_plan(*args):
+        return sm(*args)
+
+    return _Plan(fn=jax.jit(sharded_sparse_batch_plan), uses_kernel=meta.use_kernel,
+                 sharded=True, allreduce_bytes=bytes_)
 
 
 # ---------------------------------------------------------------------------
@@ -790,15 +811,16 @@ def _make_batch_stager(
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def stage(vals_list, in_fields_list, pred_masks_list):
-        vals = _stack(vals_list)
-        in_fields = tuple(
-            _stack([_pad_message(j, member[j]) for member in in_fields_list])
-            for j in range(len(in_attrs_list))
-        )
-        pred_masks = tuple(
-            jnp.stack([pm[k] for pm in pred_masks_list])
-            for k in range(len(pred_attrs))
-        )
+        with jax.named_scope("batch_stage"):
+            vals = _stack(vals_list)
+            in_fields = tuple(
+                _stack([_pad_message(j, member[j]) for member in in_fields_list])
+                for j in range(len(in_attrs_list))
+            )
+            pred_masks = tuple(
+                jnp.stack([pm[k] for pm in pred_masks_list])
+                for k in range(len(pred_attrs))
+            )
         return vals, in_fields, pred_masks
 
     return stage
@@ -815,13 +837,14 @@ def _slice_member(
     factor: placeholder dims shrink back to the member's actual sizes."""
     leaves, treedef = jax.tree_util.tree_flatten(fact.field)
     sliced = []
-    for leaf, t in zip(leaves, ring.trailing):
-        idx = tuple(
-            ([] if lead is None else [lead])
-            + [slice(0, dims.get(a, doms[a])) for a in fact.attrs]
-            + [slice(None)] * t
-        )
-        sliced.append(leaf[idx])
+    with jax.named_scope("batch_slice"):
+        for leaf, t in zip(leaves, ring.trailing):
+            idx = tuple(
+                ([] if lead is None else [lead])
+                + [slice(0, dims.get(a, doms[a])) for a in fact.attrs]
+                + [slice(None)] * t
+            )
+            sliced.append(leaf[idx])
     return Factor(fact.attrs, jax.tree_util.tree_unflatten(treedef, sliced), ring)
 
 
@@ -886,7 +909,11 @@ def _build_batched_sparse_plan(
     bfn, meta = _batched_sparse_fn(
         ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, n, member_dims
     )
-    return _Plan(fn=jax.jit(bfn), uses_kernel=meta.use_kernel)
+
+    def sparse_batch_plan(*args):
+        return bfn(*args)
+
+    return _Plan(fn=jax.jit(sparse_batch_plan), uses_kernel=meta.use_kernel)
 
 
 # ---------------------------------------------------------------------------
@@ -958,6 +985,7 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
     group_kernel = tuple(p["meta"].use_kernel for p in parts)
     fused_messages = sum(len(p["member_dims"]) for p in parts if p["fused"])
     op = ring.kernel_segment_op
+    reduce_scope = _reduce_scope(ring)
 
     def lfn(groups_args):
         fused_items: list = []
@@ -971,41 +999,44 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
             vals_list, in_fields_list, in_idx, pred_masks_list, pred_codes, seg_idx = args
             nmembers = len(part["member_dims"])
             if nmembers == 1:
-                member_rvs = [part["rowwise"](
+                rvs = part["rowwise"](
                     vals_list[0], in_fields_list[0], in_idx,
                     pred_masks_list[0], pred_codes,
-                )]
+                )
             else:
                 vals, in_fields, pred_masks = part["stage"](
                     vals_list, in_fields_list, pred_masks_list
                 )
-                rvb = jax.vmap(part["rowwise"], in_axes=(0, 0, None, 0, None))(
+                rvs = jax.vmap(part["rowwise"], in_axes=(0, 0, None, 0, None))(
                     vals, in_fields, in_idx, pred_masks, pred_codes
                 )
-                member_rvs = [
-                    jax.tree_util.tree_map(lambda l, b=b: l[b], rvb)
-                    for b in range(nmembers)
-                ]
             n = part["n"]
-            for b, rv in enumerate(member_rvs):
-                leaves, treedef = jax.tree_util.tree_flatten(rv)
-                treedefs[g] = treedef
-                slab = jnp.concatenate(
-                    [l.reshape((n, -1)) for l in leaves], axis=1
-                )
-                fused_items.append((seg_idx, slab, part["meta"].total))
-                fused_slots.append((g, b))
+            # each member's slab of the fused launch: the reduction's input
+            with jax.named_scope(reduce_scope):
+                for b in range(nmembers):
+                    rv = rvs if nmembers == 1 else jax.tree_util.tree_map(
+                        lambda l, b=b: l[b], rvs
+                    )
+                    leaves, treedef = jax.tree_util.tree_flatten(rv)
+                    treedefs[g] = treedef
+                    slab = jnp.concatenate(
+                        [l.reshape((n, -1)) for l in leaves], axis=1
+                    )
+                    fused_items.append((seg_idx, slab, part["meta"].total))
+                    fused_slots.append((g, b))
         if fused_items:
-            fused_outs = seg_ops.level_aggregate(fused_items, op=op)
+            with jax.named_scope(reduce_scope):
+                fused_outs = seg_ops.level_aggregate(fused_items, op=op)
             fused_facts: dict = {}
             for (g, b), agg in zip(fused_slots, fused_outs):
                 part = parts[g]
                 total = part["meta"].total
                 carried_dims = part["meta"].carried_dims
-                leaf_parts = (
-                    jnp.split(agg, nleaves, axis=1) if nleaves > 1 else [agg]
-                )
-                red = [p.reshape((total,) + carried_dims) for p in leaf_parts]
+                with jax.named_scope(reduce_scope):
+                    leaf_parts = (
+                        jnp.split(agg, nleaves, axis=1) if nleaves > 1 else [agg]
+                    )
+                    red = [p.reshape((total,) + carried_dims) for p in leaf_parts]
                 field = jax.tree_util.tree_unflatten(treedefs[g], red)
                 fact = part["finalize"](field)
                 fact = _slice_member(
@@ -1021,8 +1052,12 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
 
 def _build_level_plan(ring: sr.Semiring, group_statics: tuple) -> _Plan:
     lfn, group_kernel, fused_messages = _level_plan_parts(ring, group_statics)
+
+    def level_plan(groups_args):
+        return lfn(groups_args)
+
     return _Plan(
-        fn=jax.jit(lfn),
+        fn=jax.jit(level_plan),
         uses_kernel=any(group_kernel),
         group_kernel=group_kernel,
         fused_messages=fused_messages,
@@ -1066,8 +1101,12 @@ def _build_sharded_level_plan(
         for (_ra, doms, _ic, _pa, out_canon, _n, member_dims) in group_statics
         for md in member_dims
     )
+
+    def sharded_level_plan(groups_args):
+        return sm(groups_args)
+
     return _Plan(
-        fn=jax.jit(sm),
+        fn=jax.jit(sharded_level_plan),
         uses_kernel=any(group_kernel),
         group_kernel=group_kernel,
         fused_messages=fused_messages,
@@ -1121,41 +1160,63 @@ def _build_dense_plan(
         if cand is not None and (_on_tpu() or cand[4] <= _kernel_cost_max()):
             split = cand
 
-    def fn(fields, pred_masks):
-        factors = [Factor(attrs, f, ring) for (attrs, _), f in zip(structs, fields)]
-        for (attr, fidx), mask in zip(pred_spec, pred_masks):
-            factors[fidx] = factors[fidx].select(attr, mask)
-        if split is not None:
-            shared, free1, free2, doms, _ = split
-            g1 = factors[0].project_to(free1 + shared)
-            g2 = factors[1].project_to(shared + free2)
-            f1sz = int(np.prod([doms[a] for a in free1])) if free1 else 1
-            f2sz = int(np.prod([doms[a] for a in free2])) if free2 else 1
-            csz = int(np.prod([doms[a] for a in shared]))
-            if tropical:
-                o = tc_ops.contract_op(
-                    g1.field.reshape((f1sz, csz)),
-                    g2.field.reshape((csz, f2sz)),
-                    is_min=ring.kernel_segment_op == "min",
+    def dense_plan(fields, pred_masks):
+        with jax.named_scope("dense_contract"):
+            factors = [Factor(attrs, f, ring) for (attrs, _), f in zip(structs, fields)]
+            for (attr, fidx), mask in zip(pred_spec, pred_masks):
+                factors[fidx] = factors[fidx].select(attr, mask)
+            if split is not None:
+                shared, free1, free2, doms, _ = split
+                g1 = factors[0].project_to(free1 + shared)
+                g2 = factors[1].project_to(shared + free2)
+                f1sz = int(np.prod([doms[a] for a in free1])) if free1 else 1
+                f2sz = int(np.prod([doms[a] for a in free2])) if free2 else 1
+                csz = int(np.prod([doms[a] for a in shared]))
+                if tropical:
+                    o = tc_ops.contract_op(
+                        g1.field.reshape((f1sz, csz)),
+                        g2.field.reshape((csz, f2sz)),
+                        is_min=ring.kernel_segment_op == "min",
+                    )
+                else:
+                    o = sc_ops.contract_op(
+                        g1.field.reshape((f1sz, csz)),
+                        g2.field.reshape((csz, f2sz)),
+                        None,
+                    )
+                field = o.reshape(
+                    tuple(doms[a] for a in free1) + tuple(doms[a] for a in free2)
                 )
-            else:
-                o = sc_ops.contract_op(
-                    g1.field.reshape((f1sz, csz)),
-                    g2.field.reshape((csz, f2sz)),
-                    None,
-                )
-            field = o.reshape(
-                tuple(doms[a] for a in free1) + tuple(doms[a] for a in free2)
-            )
-            return Factor(free1 + free2, field, ring).project_to(out)
-        return contract(factors, out, ring)
+                return Factor(free1 + free2, field, ring).project_to(out)
+            return contract(factors, out, ring)
 
-    return _Plan(fn=jax.jit(fn), uses_kernel=split is not None)
+    return _Plan(fn=jax.jit(dense_plan), uses_kernel=split is not None)
 
 
 # ---------------------------------------------------------------------------
 # the cache
 # ---------------------------------------------------------------------------
+
+def _run_span(kind: str):
+    """``treant.plans.run`` around each call of a ``PlanCache.run_*``
+    dispatch: its inputs gathered, its plan found or built, and called."""
+
+    def wrap(method):
+        @functools.wraps(method)
+        def run(self, *args, **kwargs):
+            with span("treant.plans.run", kind=kind):
+                return method(self, *args, **kwargs)
+
+        return run
+
+    return wrap
+
+
+def _building(kind: str, traced: bool):
+    """``treant.plans.build`` around a new plan's build and first call,
+    where JAX traces and compiles it; nothing for a plan the cache holds."""
+    return span("treant.plans.build", kind=kind) if traced else contextlib.nullcontext()
+
 
 class PlanCache:
     """Compiled-executable cache for bag contractions (one per engine/ring).
@@ -1263,6 +1324,7 @@ class PlanCache:
             _field_struct(vals),
         )
 
+    @_run_span("sparse")
     def run_sparse(
         self,
         catalog,
@@ -1279,37 +1341,39 @@ class PlanCache:
             key = key + (("shards", shards),)
         entry = self._plans.get(key)
         traced = entry is None
-        if traced:
-            doms = dict(rel.domains)
+        with _building("sparse", traced):
+            if traced:
+                doms = dict(rel.domains)
+                for m in incoming:
+                    doms.update(m.domains)
+                build_args = (
+                    self.ring, rel.attrs, doms, tuple(m.attrs for m in incoming),
+                    tuple(p.attr for p in preds), tuple(out_attrs), rel.row_bucket,
+                )
+                entry = (
+                    _build_sharded_sparse_plan(*build_args, self.mesh, self.mesh_axis)
+                    if shards > 1 else _build_sparse_plan(*build_args)
+                )
+                self._plans.put(key, entry)
+            rel_set = set(rel.attrs)
+            in_fields, in_idx = [], []
             for m in incoming:
-                doms.update(m.domains)
-            build_args = (
-                self.ring, rel.attrs, doms, tuple(m.attrs for m in incoming),
-                tuple(p.attr for p in preds), tuple(out_attrs), rel.row_bucket,
+                shared = tuple(a for a in m.attrs if a in rel_set)
+                in_fields.append(m.field)
+                in_idx.append(catalog.dev_flat_codes(rel, shared)[0] if shared else None)
+            pred_masks = tuple(self.mask_dev(p) for p in preds)
+            pred_codes = tuple(catalog.dev_flat_codes(rel, (p.attr,))[0] for p in preds)
+            local_out = tuple(a for a in out_attrs if a in rel_set)
+            seg_idx, _ = catalog.dev_flat_codes(rel, local_out)
+            out = entry.fn(
+                vals, tuple(in_fields), tuple(in_idx), pred_masks, pred_codes, seg_idx
             )
-            entry = (
-                _build_sharded_sparse_plan(*build_args, self.mesh, self.mesh_axis)
-                if shards > 1 else _build_sparse_plan(*build_args)
-            )
-            self._plans.put(key, entry)
-        rel_set = set(rel.attrs)
-        in_fields, in_idx = [], []
-        for m in incoming:
-            shared = tuple(a for a in m.attrs if a in rel_set)
-            in_fields.append(m.field)
-            in_idx.append(catalog.dev_flat_codes(rel, shared)[0] if shared else None)
-        pred_masks = tuple(self.mask_dev(p) for p in preds)
-        pred_codes = tuple(catalog.dev_flat_codes(rel, (p.attr,))[0] for p in preds)
-        local_out = tuple(a for a in out_attrs if a in rel_set)
-        seg_idx, _ = catalog.dev_flat_codes(rel, local_out)
-        out = entry.fn(
-            vals, tuple(in_fields), tuple(in_idx), pred_masks, pred_codes, seg_idx
-        )
         self._account(entry, traced, stats)
         if entry.sharded:
             self._account_sharded(entry, (rel,))
         return out
 
+    @_run_span("sparse_batch")
     def run_sparse_batch(
         self,
         catalog,
@@ -1326,6 +1390,7 @@ class PlanCache:
         """
         return self._run_batch(catalog, items, stats_list, calibration=False)
 
+    @_run_span("message_batch")
     def run_message_batch(
         self,
         catalog,
@@ -1343,6 +1408,7 @@ class PlanCache:
         """
         return self._run_batch(catalog, items, stats_list, calibration=True)
 
+    @_run_span("level")
     def run_level(
         self,
         catalog,
@@ -1375,25 +1441,26 @@ class PlanCache:
             key = key + (("shards", shards),)
         entry = self._plans.get(key)
         traced = entry is None
-        if traced:
-            statics = tuple(
-                (
-                    specs[i].items[0].rel.attrs, specs[i].doms,
-                    specs[i].in_canon, specs[i].pred_attrs, specs[i].out_canon,
-                    specs[i].items[0].rel.row_bucket, specs[i].member_dims,
+        with _building("level", traced):
+            if traced:
+                statics = tuple(
+                    (
+                        specs[i].items[0].rel.attrs, specs[i].doms,
+                        specs[i].in_canon, specs[i].pred_attrs, specs[i].out_canon,
+                        specs[i].items[0].rel.row_bucket, specs[i].member_dims,
+                    )
+                    for i in order
                 )
-                for i in order
-            )
-            entry = (
-                _build_sharded_level_plan(
-                    self.ring, statics, self.mesh, self.mesh_axis
+                entry = (
+                    _build_sharded_level_plan(
+                        self.ring, statics, self.mesh, self.mesh_axis
+                    )
+                    if shards > 1 else _build_level_plan(self.ring, statics)
                 )
-                if shards > 1 else _build_level_plan(self.ring, statics)
+                self._plans.put(key, entry)
+            outs = entry.fn(
+                tuple(self._group_args(catalog, specs[i]) for i in order)
             )
-            self._plans.put(key, entry)
-        outs = entry.fn(
-            tuple(self._group_args(catalog, specs[i]) for i in order)
-        )
         if entry.fused_messages:
             self.stats.fused_level_launches += 1
             self.stats.fused_level_messages += entry.fused_messages
@@ -1534,19 +1601,20 @@ class PlanCache:
         key = spec.key + (("shards", shards),) if shards > 1 else spec.key
         entry = self._plans.get(key)
         traced = entry is None
-        if traced:
-            build_args = (
-                self.ring, rel.attrs, spec.doms, spec.in_canon, spec.pred_attrs,
-                spec.out_canon, rel.row_bucket, spec.member_dims,
-            )
-            entry = (
-                _build_sharded_batched_sparse_plan(
-                    *build_args, self.mesh, self.mesh_axis
+        with _building("message_batch" if calibration else "sparse_batch", traced):
+            if traced:
+                build_args = (
+                    self.ring, rel.attrs, spec.doms, spec.in_canon, spec.pred_attrs,
+                    spec.out_canon, rel.row_bucket, spec.member_dims,
                 )
-                if shards > 1 else _build_batched_sparse_plan(*build_args)
-            )
-            self._plans.put(key, entry)
-        outs = entry.fn(*self._group_args(catalog, spec))
+                entry = (
+                    _build_sharded_batched_sparse_plan(
+                        *build_args, self.mesh, self.mesh_axis
+                    )
+                    if shards > 1 else _build_batched_sparse_plan(*build_args)
+                )
+                self._plans.put(key, entry)
+            outs = entry.fn(*self._group_args(catalog, spec))
         if entry.sharded:
             self._account_sharded(entry, (rel,))
         width = len(items)
@@ -1574,6 +1642,7 @@ class PlanCache:
         # undo the canonical sort: caller expects its own member order
         return [results[inverse[o]] for o in range(width)]
 
+    @_run_span("dense")
     def run_dense(
         self,
         factors: Sequence[Factor],
@@ -1594,12 +1663,13 @@ class PlanCache:
         key = ("dense", self.ring.name, structs, pred_spec, tuple(out_attrs))
         entry = self._plans.get(key)
         traced = entry is None
-        if traced:
-            entry = _build_dense_plan(self.ring, structs, pred_spec, tuple(out_attrs))
-            self._plans.put(key, entry)
-        out = entry.fn(
-            tuple(f.field for f in factors), tuple(self.mask_dev(p) for p in preds)
-        )
+        with _building("dense", traced):
+            if traced:
+                entry = _build_dense_plan(self.ring, structs, pred_spec, tuple(out_attrs))
+                self._plans.put(key, entry)
+            out = entry.fn(
+                tuple(f.field for f in factors), tuple(self.mask_dev(p) for p in preds)
+            )
         self._account(entry, traced, stats)
         return out
 

@@ -75,6 +75,7 @@ from repro.core.dashboard import (
 )
 from repro.core.query import Query
 from repro.core.treant import Treant
+from repro.trace import span
 
 
 class QueueFull(RuntimeError):
@@ -101,6 +102,7 @@ class ServeStats:
     background_flushes: int = 0       # flush() ticks run off the caller thread
     think_time_messages: int = 0      # calibration edges advanced while idle
     errors: int = 0                   # events whose _record raised
+    queue_wait_s: float = 0.0         # Σ over processed events of submit → drained
 
 
 @dataclasses.dataclass
@@ -108,6 +110,7 @@ class _Queued:
     sid: str
     event: object
     seq: int
+    at: float  # time.perf_counter() at submit
 
 
 @dataclasses.dataclass
@@ -251,7 +254,7 @@ class TreantServer:
                 )
             self.stats_.backpressure_drains += 1
             self.step()
-        self._queue.append(_Queued(sid, event, self._seq))
+        self._queue.append(_Queued(sid, event, self._seq, time.perf_counter()))
         self._seq += 1
         self.stats_.queue_peak = max(self.stats_.queue_peak, len(self._queue))
 
@@ -322,26 +325,31 @@ class TreantServer:
         batch = self._next_batch()
         if not batch:
             return 0
-        self.stats_.batches += 1
-        # batch boundary: last batch's pool hits lose their eviction shield
-        for pooled in self._pool.values():
-            pooled.hot = False
-        participants: list[tuple[ServerSession, object]] = []
-        for q in batch:
-            handle = self._sessions.get(q.sid)
-            if handle is None:  # closed while queued
-                continue
-            try:
-                changed = handle.session._record(q.event)
-            except Exception:
-                self.stats_.errors += 1
-                continue
-            self.stats_.events_processed += 1
-            if changed:
-                participants.append((handle, q.event))
-        self._fan_out(participants)
-        for handle, _ in participants:
-            handle._refresh_pin()
+        drained = time.perf_counter()
+        with span("treant.serve.step", batch=self.stats_.batches, events=len(batch)):
+            self.stats_.batches += 1
+            # batch boundary: last batch's pool hits lose their eviction shield
+            for pooled in self._pool.values():
+                pooled.hot = False
+            participants: list[tuple[ServerSession, object]] = []
+            with span("treant.serve.record"):
+                for q in batch:
+                    handle = self._sessions.get(q.sid)
+                    if handle is None:  # closed while queued
+                        continue
+                    try:
+                        changed = handle.session._record(q.event)
+                    except Exception:
+                        self.stats_.errors += 1
+                        continue
+                    self.stats_.events_processed += 1
+                    self.stats_.queue_wait_s += drained - q.at
+                    if changed:
+                        participants.append((handle, q.event))
+            with span("treant.serve.fan_out"):
+                self._fan_out(participants)
+            for handle, _ in participants:
+                handle._refresh_pin()
         return len(batch)
 
     def _fan_out(self, participants: list[tuple[ServerSession, object]]) -> None:
@@ -364,44 +372,45 @@ class TreantServer:
         #    the server's shared pool (any session may hit another's parked
         #    speculation — digests are session-agnostic), then bin cubes —
         #    session-local and pooled — which cover ANY σ on their dimension
-        to_exec: list[tuple[ServerSession, str, Query]] = []
-        pool_dims = sorted({
-            e.dim for e in self._pool.values() if e.dim is not None
-        })
-        for handle, viz, q in work:
-            sess = handle.session
-            hit = sess._prefetched.pop((viz, q.digest), None)
-            if hit is not None:
-                sess.prefetch_hits += 1
-                results[(handle.id, viz)] = InteractionResult(
-                    hit.factor, ExecStats(prefetch_hits=1), 0.0, 0
-                )
-                continue
-            pooled = self._pool.get(q.digest)
-            if pooled is not None:
-                self.stats_.shared_prefetch_hits += 1
-                # a hit refreshes recency (reinsert at the warm end) and
-                # shields the entry from eviction for the rest of this batch
-                del self._pool[q.digest]
-                self._pool[q.digest] = pooled
-                pooled.hot = True
-                results[(handle.id, viz)] = InteractionResult(
-                    pooled.factor, ExecStats(prefetch_hits=1), 0.0, 0
-                )
-                continue
-            sliced = sess._probe_bin_cube(viz, q)
-            if sliced is not None:
-                results[(handle.id, viz)] = InteractionResult(
-                    sliced, ExecStats(bin_cube_hits=1), 0.0, 0
-                )
-                continue
-            sliced = self._probe_pool_cube(sess, q, pool_dims)
-            if sliced is not None:
-                results[(handle.id, viz)] = InteractionResult(
-                    sliced, ExecStats(bin_cube_hits=1), 0.0, 0
-                )
-                continue
-            to_exec.append((handle, viz, q))
+        with span("treant.serve.probe"):
+            to_exec: list[tuple[ServerSession, str, Query]] = []
+            pool_dims = sorted({
+                e.dim for e in self._pool.values() if e.dim is not None
+            })
+            for handle, viz, q in work:
+                sess = handle.session
+                hit = sess._prefetched.pop((viz, q.digest), None)
+                if hit is not None:
+                    sess.prefetch_hits += 1
+                    results[(handle.id, viz)] = InteractionResult(
+                        hit.factor, ExecStats(prefetch_hits=1), 0.0, 0
+                    )
+                    continue
+                pooled = self._pool.get(q.digest)
+                if pooled is not None:
+                    self.stats_.shared_prefetch_hits += 1
+                    # a hit refreshes recency (reinsert at the warm end) and
+                    # shields the entry from eviction for the rest of this batch
+                    del self._pool[q.digest]
+                    self._pool[q.digest] = pooled
+                    pooled.hot = True
+                    results[(handle.id, viz)] = InteractionResult(
+                        pooled.factor, ExecStats(prefetch_hits=1), 0.0, 0
+                    )
+                    continue
+                sliced = sess._probe_bin_cube(viz, q)
+                if sliced is not None:
+                    results[(handle.id, viz)] = InteractionResult(
+                        sliced, ExecStats(bin_cube_hits=1), 0.0, 0
+                    )
+                    continue
+                sliced = self._probe_pool_cube(sess, q, pool_dims)
+                if sliced is not None:
+                    results[(handle.id, viz)] = InteractionResult(
+                        sliced, ExecStats(bin_cube_hits=1), 0.0, 0
+                    )
+                    continue
+                to_exec.append((handle, viz, q))
         # 2) dedupe identical queries across sessions: execute once, share
         #    the factor (the shared-spec same-σ case)
         first_of: dict[str, tuple[ServerSession, str, Query]] = {}
@@ -440,54 +449,56 @@ class TreantServer:
                 pending.append(factor)
                 self._schedule(handle, viz, q, engine)
         if pending:
-            jax.block_until_ready([f.field for f in pending])
-        # cross-session width: the max of (a) distinct sessions inside one
-        # vmapped dispatch and (b) distinct sessions sharing one deduped
-        # execution — both are "one dispatch served k sessions"
-        width = max(
-            (st.batch_sessions for _, st in executed.values()), default=0
-        )
-        for digest, flw in followers.items():
-            owners = {h.id for h, _ in flw} | {first_of[digest][0].id}
-            width = max(width, len(owners))
-        self.stats_.cross_session_batch_width = max(
-            self.stats_.cross_session_batch_width, width
-        )
-        # 4) distribute: leaders
-        for digest, (handle, viz, q) in first_of.items():
-            factor, stats = executed[digest]
-            results[(handle.id, viz)] = InteractionResult(
-                factor, stats, 0.0, stats.steiner_size
+            with span("treant.serve.wait"):
+                jax.block_until_ready([f.field for f in pending])
+        with span("treant.serve.distribute"):
+            # cross-session width: the max of (a) distinct sessions inside one
+            # vmapped dispatch and (b) distinct sessions sharing one deduped
+            # execution — both are "one dispatch served k sessions"
+            width = max(
+                (st.batch_sessions for _, st in executed.values()), default=0
             )
-        #    followers share the leader's factor verbatim (bit-identical by
-        #    construction) and re-schedule their own calibration
-        for digest, flw in followers.items():
-            factor, _ = executed[digest]
-            for handle, viz in flw:
-                self.stats_.dedup_hits += 1
+            for digest, flw in followers.items():
+                owners = {h.id for h, _ in flw} | {first_of[digest][0].id}
+                width = max(width, len(owners))
+            self.stats_.cross_session_batch_width = max(
+                self.stats_.cross_session_batch_width, width
+            )
+            # 4) distribute: leaders
+            for digest, (handle, viz, q) in first_of.items():
+                factor, stats = executed[digest]
                 results[(handle.id, viz)] = InteractionResult(
-                    factor, ExecStats(messages_reused=1), 0.0, 0
+                    factor, stats, 0.0, stats.steiner_size
                 )
-        # 5) commit per-session view state; park calibration for every
-        #    re-rendered viz that was NOT a leader (leaders scheduled above)
-        leaders = {(h.id, v) for h, v, _ in uniques}
-        for handle, viz, q in work:
-            handle.session._current[viz] = q
-            if (handle.id, viz) not in leaders:
-                engine = self.treant.engine_for(q.ring_name, q.measure)
-                self._schedule(handle, viz, q, engine)
-        for handle, event in participants:
-            sess = handle.session
-            derived = derived_by_sid[handle.id]
-            affected = tuple(
-                viz for h, viz, _ in work if h.id == handle.id
-            )
-            handle.last_result = ApplyResult(
-                event, affected,
-                {viz: results[(handle.id, viz)]
-                 for viz in affected if (handle.id, viz) in results},
-                derived, 0.0,
-            )
+            #    followers share the leader's factor verbatim (bit-identical by
+            #    construction) and re-schedule their own calibration
+            for digest, flw in followers.items():
+                factor, _ = executed[digest]
+                for handle, viz in flw:
+                    self.stats_.dedup_hits += 1
+                    results[(handle.id, viz)] = InteractionResult(
+                        factor, ExecStats(messages_reused=1), 0.0, 0
+                    )
+            # 5) commit per-session view state; park calibration for every
+            #    re-rendered viz that was NOT a leader (leaders scheduled above)
+            leaders = {(h.id, v) for h, v, _ in uniques}
+            for handle, viz, q in work:
+                handle.session._current[viz] = q
+                if (handle.id, viz) not in leaders:
+                    engine = self.treant.engine_for(q.ring_name, q.measure)
+                    self._schedule(handle, viz, q, engine)
+            for handle, event in participants:
+                sess = handle.session
+                derived = derived_by_sid[handle.id]
+                affected = tuple(
+                    viz for h, viz, _ in work if h.id == handle.id
+                )
+                handle.last_result = ApplyResult(
+                    event, affected,
+                    {viz: results[(handle.id, viz)]
+                     for viz in affected if (handle.id, viz) in results},
+                    derived, 0.0,
+                )
 
     def _schedule(self, handle: ServerSession, viz: str, q: Query,
                   engine: CJTEngine) -> None:
@@ -532,24 +543,28 @@ class TreantServer:
         """
         if self._queue:
             return 0  # queued interactive work always wins
-        if any(b.has_pending for b in self.treant._streams.values()):
-            self.treant.flush()
-            self.stats_.background_flushes += 1
-            for handle in self._sessions.values():
-                handle._refresh_pin()
-        budget = (
-            budget_messages if budget_messages is not None
-            else self.think_budget_messages
-        )
-        done = self.treant.scheduler.run(budget_messages=budget)
-        self.stats_.think_time_messages += done
-        policy = self.policy or self.treant.think_time_policy
-        extras_budget = ThinkTimeBudget()
-        for sid in sorted(self._sessions):
-            sess = self._sessions[sid].session
-            policy.extras(sess, extras_budget, time.perf_counter())
-            self._absorb_prefetch(sess)
-            self._absorb_cubes(sess)
+        with span("treant.serve.idle"):
+            if any(b.has_pending for b in self.treant._streams.values()):
+                with span("treant.serve.flush"):
+                    self.treant.flush()
+                self.stats_.background_flushes += 1
+                for handle in self._sessions.values():
+                    handle._refresh_pin()
+            budget = (
+                budget_messages if budget_messages is not None
+                else self.think_budget_messages
+            )
+            with span("treant.scheduler.run"):
+                done = self.treant.scheduler.run(budget_messages=budget)
+            self.stats_.think_time_messages += done
+            policy = self.policy or self.treant.think_time_policy
+            extras_budget = ThinkTimeBudget()
+            with span("treant.policy.extras"):
+                for sid in sorted(self._sessions):
+                    sess = self._sessions[sid].session
+                    policy.extras(sess, extras_budget, time.perf_counter())
+                    self._absorb_prefetch(sess)
+                    self._absorb_cubes(sess)
         return done
 
     def _absorb_prefetch(self, sess: Session) -> None:

@@ -572,3 +572,29 @@ def test_serve_counters_surface_in_cache_stats():
         assert key in st, key
     assert st["sessions"] == 1 and st["events_processed"] == 1
     assert st["byte_budget"] == 1 << 20
+
+
+def test_queue_wait_grows_by_the_wait_of_each_processed_event():
+    """``queue_wait_s`` adds, for every event a step processes, the time
+    from its ``submit`` until the step drained its batch."""
+    import time
+
+    spec = spec_for("sum")
+    t = Treant(star_catalog(), use_plans=True)
+    server = TreantServer(t)
+    a, b = (server.open_session(spec, name=n) for n in ("a", "b"))
+    t0 = time.perf_counter()
+    a.submit(brush(0, 3))
+    time.sleep(0.05)
+    b.submit(brush(1, 4))
+    assert server.stats_.queue_wait_s == 0.0
+    server.step()
+    elapsed = time.perf_counter() - t0
+    st = t.cache_stats()["serve"]
+    assert st["events_processed"] == 2
+    # a waited the 50 ms sleep at least; neither waited past the step
+    assert 0.05 <= st["queue_wait_s"] <= 2 * elapsed
+    before = st["queue_wait_s"]
+    a.submit(brush(2, 5))
+    server.step()
+    assert server.stats_.queue_wait_s > before
