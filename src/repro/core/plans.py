@@ -151,7 +151,8 @@ def expand_rows_field(field: sr.Field, have: Sequence[str], want: Sequence[str],
     """Insert size-1 axes so leaves go (N, *have_dims, *t) → (N, *want_dims, *t).
 
     ``have`` must be a subsequence of ``want``; trailing statistic dims ride
-    along unchanged.  Shared by the compiled plans and the legacy sparse path.
+    along unchanged.  The legacy sparse path's row-major layout; the compiled
+    plans' rowwise stage is lane-major (:func:`_expand_cells`).
     """
     leaves, treedef = jax.tree_util.tree_flatten(field)
     out = []
@@ -167,6 +168,18 @@ def expand_rows_field(field: sr.Field, have: Sequence[str], want: Sequence[str],
                 new_shape.append(1)
         new_shape += cur[hi:]
         out.append(leaf.reshape(new_shape))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _expand_cells(field: sr.Field, have: Sequence[str], want: Sequence[str]) -> sr.Field:
+    """Insert size-1 axes so leaves go (*have_dims, N, *t) → (*want_dims, N, *t):
+    the lane-major layout of the plans' rowwise stage, rows after the cells."""
+    leaves, treedef = jax.tree_util.tree_flatten(field)
+    out = []
+    for leaf in leaves:
+        cells = iter(leaf.shape[: len(have)])
+        shape = tuple(next(cells) if a in have else 1 for a in want)
+        out.append(leaf.reshape(shape + leaf.shape[len(have):]))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -210,6 +223,11 @@ class PlanStats:
     # (select + ⊕-marginalize — no plan execution, no store probe)
     cube_builds: int = 0
     cube_slices: int = 0
+    # rowwise gathers of executed sparse plans, per member, from each plan's
+    # static count: tables (dimension messages, σ masks) gathered at the row
+    # codes by one-hot contraction, and by ``jnp.take``
+    onehot_gathers: int = 0
+    take_gathers: int = 0
 
     # counters that are high-water marks, not sums: cross-engine aggregation
     # (Treant.cache_stats) takes max for these and Σ for everything else
@@ -312,6 +330,9 @@ class _Plan:
     # collective payloads (one per output factor per dispatch)
     sharded: bool = False
     allreduce_bytes: int = 0
+    # rowwise gathers per member as (one-hot, take); level plans: per group
+    gathers: tuple = (0, 0)
+    group_gathers: tuple = ()
 
 
 # ---------------------------------------------------------------------------
@@ -328,6 +349,7 @@ class _SparseMeta:
     use_kernel: bool
     cost: int
     row_bytes: int                   # device bytes the body holds per row
+    gathers: tuple[int, int]         # rowwise gathers: (one-hot, take)
 
 
 # ---------------------------------------------------------------------------
@@ -420,6 +442,53 @@ def _blocked(build: Callable, n: int, ring: sr.Semiring, row_major):
     return _scan_rows(body, ring, blocks, row_major), info
 
 
+# ---------------------------------------------------------------------------
+# rowwise gathers: a table at the row codes, written with the rows on the lanes
+# ---------------------------------------------------------------------------
+
+# A dimension message or a σ domain mask is a (D, k) table gathered at the
+# fact rows' codes.  ``jnp.take`` writes its (N, k) result with k on the 128
+# lanes, padded up to 128, and a relayout follows; contracting the table with
+# the one-hot ``codes == iota(D)`` on the MXU writes (k, N), rows on the lanes,
+# and XLA fuses the compare into the dot, so the one-hot is never written.
+# Measured on a TPU v5e at 2^23 rows: the contraction takes 1-7 ms up to
+# D = 265 and grows with D (36 ms at 2048, 76 ms at 4096, for 7 columns of 2
+# members); ``take`` takes 35-93 ms from D = 96 up, but under 1 ms for a
+# single column of at most 64 entries.
+ONEHOT_MAX_DOMAIN = 4096
+TAKE_MAX_DOMAIN = 64
+
+
+def _onehot_rows(table: jax.Array, codes: jax.Array) -> jax.Array:
+    """A (D, *cells) table at the (N,) row codes as (*cells, N), by one-hot
+    contraction.  At ``HIGHEST`` every pass is exact against a 0/1 one-hot,
+    so this equals ``jnp.take`` bit for bit wherever the table is finite."""
+    d = table.shape[0]
+    hot = jax.lax.broadcasted_iota(codes.dtype, (d,) + codes.shape, 0) == codes[None]
+    out = jax.lax.dot_general(
+        table.reshape((d, -1)).astype(jnp.float32), hot.astype(jnp.float32),
+        (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    return out.reshape(table.shape[1:] + codes.shape)
+
+
+def _take_rows(table: jax.Array, codes: jax.Array, ncells: int) -> jax.Array:
+    """A (D, *cells, *t) table at the row codes as (*cells, N, *t).  Codes
+    are in range by construction, so ``clip`` only drops the fill pass."""
+    return jnp.take(jnp.moveaxis(table, 0, ncells), codes, axis=ncells, mode="clip")
+
+
+def _use_onehot(finite: bool, d: int, cols: int) -> bool:
+    """Whether a rowwise gather of a D-entry table contracts a one-hot: only
+    where every entry is finite (0·x is NaN for x = ±inf, the tropical 0̄),
+    D is at most :data:`ONEHOT_MAX_DOMAIN`, and the gather is not a single
+    column (one member, k = 1) of at most :data:`TAKE_MAX_DOMAIN` entries,
+    which ``take`` writes densely and fast."""
+    return finite and d <= ONEHOT_MAX_DOMAIN and (cols > 1 or d > TAKE_MAX_DOMAIN)
+
+
 def _sparse_plan_parts(
     ring: sr.Semiring,
     rel_attrs: tuple[str, ...],
@@ -428,12 +497,17 @@ def _sparse_plan_parts(
     pred_attrs: tuple[str, ...],
     out_attrs: tuple[str, ...],
     n: int,
+    members: int = 1,
 ) -> tuple[Callable, Callable, Callable, _SparseMeta]:
     """The raw (un-jitted) single-contraction body shared by the scalar plan
-    (jit directly) and the batched plan (pad + stack + vmap, then jit),
-    split as (fn, rowwise, finalize, meta) so the level-fused plan can run
-    the rowwise stage per message and hand ALL segment reductions of a level
-    to one multi-segment kernel launch between rowwise and finalize."""
+    (jit directly) and the batched plan (pad + stack + vmap over ``members``,
+    then jit), split as (fn, rowwise, finalize, meta) so the level-fused plan
+    can run the rowwise stage per message and hand ALL segment reductions of
+    a level to one multi-segment kernel launch between rowwise and finalize.
+
+    The rowwise stage is lane-major: every intermediate is (*cells, N, *t),
+    the rows after the carried γ cells, so for scalar rings the slab handed
+    to the segment reduction is already the kernel's (V, N) layout."""
     rel_set = set(rel_attrs)
     local_out = tuple(a for a in out_attrs if a in rel_set)
     total = int(np.prod([doms[a] for a in local_out])) if local_out else 1
@@ -453,6 +527,22 @@ def _sparse_plan_parts(
         f"carried attrs {carried_out} not available (have {list(carried)})"
     )
 
+    # each gather's path, from the ring and the table's shape: messages of
+    # the rings whose ⊗ is × and 0̄ is 0 (f32 SUM/COUNT) may go one-hot, σ
+    # masks (0/1 values) in every ring
+    arith = ring.is_arithmetic and ring.dtype == jnp.float32 and ring.trailing == (0,)
+    msg_onehot = tuple(
+        _use_onehot(
+            arith,
+            int(np.prod([doms[a] for a in shared])),
+            members * int(np.prod([doms[a] for a in extra])),
+        ) if shared else None
+        for _, shared, extra, _, _ in steps
+    )
+    mask_onehot = tuple(_use_onehot(True, doms[a], members) for a in pred_attrs)
+    paths = [p for p in msg_onehot + mask_onehot if p is not None]
+    gathers = (sum(paths), len(paths) - sum(paths))
+
     op = ring.kernel_segment_op
     vcols = int(np.prod(carried_dims)) if carried_dims else 1
     cost = n * max(total, 1) * vcols * len(ring.trailing)
@@ -464,42 +554,50 @@ def _sparse_plan_parts(
         and (_on_tpu() or cost <= _kernel_cost_max())
     )
     out_shape = tuple(doms[a] for a in local_out)
+    ncarried = len(carried_dims)
 
     def rowwise(vals, in_fields, in_idx, pred_masks, pred_codes):
         with jax.named_scope("rowwise"):
-            for (m_attrs, shared, extra, have, want), field, idx in zip(
-                steps, in_fields, in_idx
+            for (m_attrs, shared, extra, have, want), field, idx, onehot in zip(
+                steps, in_fields, in_idx, msg_onehot
             ):
                 mp = Factor(m_attrs, field, ring).project_to(shared + extra)
-                dims = [doms[a] for a in shared]
+                d = int(np.prod([doms[a] for a in shared])) if shared else 1
 
                 def gather(leaf):
-                    lead = leaf.reshape(
-                        (int(np.prod(dims)) if shared else 1,) + leaf.shape[len(shared):]
-                    )
+                    lead = leaf.reshape((d,) + leaf.shape[len(shared):])
+                    if onehot:
+                        return _onehot_rows(lead, idx)
                     if shared:
-                        return jnp.take(lead, idx, axis=0)
-                    return jnp.broadcast_to(lead, (n,) + lead.shape[1:])
+                        return _take_rows(lead, idx, len(extra))
+                    cells = lead.shape[1 : 1 + len(extra)]
+                    return jnp.broadcast_to(
+                        jnp.expand_dims(lead[0], len(extra)),
+                        cells + (n,) + lead.shape[1 + len(extra):],
+                    )
 
                 leaves, treedef = jax.tree_util.tree_flatten(mp.field)
                 g = jax.tree_util.tree_unflatten(treedef, [gather(l) for l in leaves])
                 vals = ring.mul(
-                    expand_rows_field(vals, have, want, ring.trailing),
-                    expand_rows_field(g, extra, want, ring.trailing),
+                    _expand_cells(vals, have, want), _expand_cells(g, extra, want)
                 )
             if pred_attrs:
                 # σ as a rowwise ⊗ with 0̄/1̄: gather each domain mask at the row
                 # codes on-device (the mask *content* is a traced arg, so new
                 # selections re-execute the same compiled plan)
-                rowm = pred_masks[0][pred_codes[0]]
-                for mask, codes in zip(pred_masks[1:], pred_codes[1:]):
-                    rowm = rowm & mask[codes]
-                zeros = ring.zeros((n,) + carried_dims)
+                rowm = None
+                for mask, codes, onehot in zip(pred_masks, pred_codes, mask_onehot):
+                    m = (
+                        _onehot_rows(mask, codes) > 0 if onehot
+                        else _take_rows(mask, codes, 0)
+                    )
+                    rowm = m if rowm is None else rowm & m
+                zeros = ring.zeros(carried_dims + (n,))
                 leaves, treedef = jax.tree_util.tree_flatten(vals)
                 zleaves = jax.tree_util.tree_leaves(zeros)
                 out = []
-                for leaf, z in zip(leaves, zleaves):
-                    m = rowm.reshape((n,) + (1,) * (leaf.ndim - 1))
+                for leaf, z, t in zip(leaves, zleaves, ring.trailing):
+                    m = rowm.reshape((1,) * ncarried + (n,) + (1,) * t)
                     out.append(jnp.where(m, leaf, z))
                 vals = jax.tree_util.tree_unflatten(treedef, out)
             return vals
@@ -516,24 +614,26 @@ def _sparse_plan_parts(
         with jax.named_scope(_reduce_scope(ring)):
             if use_kernel:
                 # compound rings (MOMENTS) stack their equal-shape leaves as
-                # extra value columns, so count/sum/sumsq share ONE segment pass
+                # extra value rows, so count/sum/sumsq share ONE segment pass
                 leaves, treedef = jax.tree_util.tree_flatten(vals)
-                slab = jnp.concatenate([l.reshape((n, -1)) for l in leaves], axis=1)
-                agg = seg_ops.aggregate_op(seg_idx, slab, total, op=op)
+                slab = jnp.concatenate([l.reshape((-1, n)) for l in leaves], axis=0)
+                agg = seg_ops.aggregate_op(seg_idx, slab, total, op=op, lane_major=True)
                 parts = jnp.split(agg, len(leaves), axis=1) if len(leaves) > 1 else [agg]
-                red = [
-                    p.reshape((total,) + l.shape[1:]) for p, l in zip(parts, leaves)
-                ]
+                red = [p.reshape((total,) + carried_dims) for p in parts]
                 field = jax.tree_util.tree_unflatten(treedef, red)
             else:
-                field = ring.segment_reduce(vals, seg_idx, total)
+                # the lax reduction wants the rows first: one transpose here
+                field = ring.segment_reduce(
+                    jax.tree_util.tree_map(lambda l: jnp.moveaxis(l, ncarried, 0), vals),
+                    seg_idx, total,
+                )
         return finalize(field)
 
-    # the rowwise slab, its σ-masked copy and the kernel's row-major copy
+    # the gathered message, its ⊗ product and the σ-masked slab
     row_bytes = 3 * vcols * len(ring.trailing) * np.dtype(ring.dtype).itemsize
     meta = _SparseMeta(
         total=total, carried_dims=carried_dims, use_kernel=use_kernel, cost=cost,
-        row_bytes=row_bytes,
+        row_bytes=row_bytes, gathers=gathers,
     )
     return fn, rowwise, finalize, meta
 
@@ -580,7 +680,8 @@ def _build_sparse_plan(
     def sparse_plan(*args):
         return fn(*args)
 
-    return _Plan(fn=jax.jit(sparse_plan), uses_kernel=meta.use_kernel)
+    return _Plan(fn=jax.jit(sparse_plan), uses_kernel=meta.use_kernel,
+                 gathers=meta.gathers)
 
 
 # ---------------------------------------------------------------------------
@@ -646,7 +747,7 @@ def _build_sharded_sparse_plan(
 
     return _Plan(
         fn=jax.jit(sharded_sparse_plan), uses_kernel=meta.use_kernel, sharded=True,
-        allreduce_bytes=_out_factor_bytes(ring, doms, out_attrs),
+        allreduce_bytes=_out_factor_bytes(ring, doms, out_attrs), gathers=meta.gathers,
     )
 
 
@@ -692,7 +793,7 @@ def _build_sharded_batched_sparse_plan(
         return sm(*args)
 
     return _Plan(fn=jax.jit(sharded_sparse_batch_plan), uses_kernel=meta.use_kernel,
-                 sharded=True, allreduce_bytes=bytes_)
+                 sharded=True, allreduce_bytes=bytes_, gathers=meta.gathers)
 
 
 # ---------------------------------------------------------------------------
@@ -866,7 +967,8 @@ def _batched_sparse_fn(
 
     def build(rows):
         fn, _, _, meta = _sparse_plan_parts(
-            ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, rows
+            ring, rel_attrs, doms, in_attrs_list, pred_attrs, out_attrs, rows,
+            nmembers,
         )
 
         def bfn(vals_list, in_fields_list, in_idx, pred_masks_list, pred_codes,
@@ -913,7 +1015,8 @@ def _build_batched_sparse_plan(
     def sparse_batch_plan(*args):
         return bfn(*args)
 
-    return _Plan(fn=jax.jit(sparse_batch_plan), uses_kernel=meta.use_kernel)
+    return _Plan(fn=jax.jit(sparse_batch_plan), uses_kernel=meta.use_kernel,
+                 gathers=meta.gathers)
 
 
 # ---------------------------------------------------------------------------
@@ -922,7 +1025,7 @@ def _build_batched_sparse_plan(
 # ---------------------------------------------------------------------------
 
 def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
-    """The raw (un-jitted) level body as ``(lfn, group_kernel,
+    """The raw (un-jitted) level body as ``(lfn, group_kernel, group_gathers,
     fused_messages)`` — split from :func:`_build_level_plan` so the sharded
     variant can wrap ``lfn`` in shard_map before jitting.
 
@@ -944,10 +1047,10 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
     for (rel_attrs, doms, in_canon, pred_attrs, out_canon, n, member_dims) in (
         group_statics
     ):
-        _, rowwise, finalize, meta = _sparse_plan_parts(
-            ring, rel_attrs, doms, in_canon, pred_attrs, out_canon, n
-        )
         nmembers = len(member_dims)
+        _, rowwise, finalize, meta = _sparse_plan_parts(
+            ring, rel_attrs, doms, in_canon, pred_attrs, out_canon, n, nmembers
+        )
         parts.append({
             "rowwise": rowwise, "finalize": finalize, "meta": meta,
             "stage": (
@@ -983,6 +1086,7 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
         else:
             p["own"], _ = _batched_sparse_fn(*p["statics"], p["member_dims"])
     group_kernel = tuple(p["meta"].use_kernel for p in parts)
+    group_gathers = tuple(p["meta"].gathers for p in parts)
     fused_messages = sum(len(p["member_dims"]) for p in parts if p["fused"])
     op = ring.kernel_segment_op
     reduce_scope = _reduce_scope(ring)
@@ -1020,13 +1124,15 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
                     leaves, treedef = jax.tree_util.tree_flatten(rv)
                     treedefs[g] = treedef
                     slab = jnp.concatenate(
-                        [l.reshape((n, -1)) for l in leaves], axis=1
+                        [l.reshape((-1, n)) for l in leaves], axis=0
                     )
                     fused_items.append((seg_idx, slab, part["meta"].total))
                     fused_slots.append((g, b))
         if fused_items:
             with jax.named_scope(reduce_scope):
-                fused_outs = seg_ops.level_aggregate(fused_items, op=op)
+                fused_outs = seg_ops.level_aggregate(
+                    fused_items, op=op, lane_major=True
+                )
             fused_facts: dict = {}
             for (g, b), agg in zip(fused_slots, fused_outs):
                 part = parts[g]
@@ -1047,11 +1153,13 @@ def _level_plan_parts(ring: sr.Semiring, group_statics: tuple) -> tuple:
                 results[g] = tuple(facts)
         return tuple(results)
 
-    return lfn, group_kernel, fused_messages
+    return lfn, group_kernel, group_gathers, fused_messages
 
 
 def _build_level_plan(ring: sr.Semiring, group_statics: tuple) -> _Plan:
-    lfn, group_kernel, fused_messages = _level_plan_parts(ring, group_statics)
+    lfn, group_kernel, group_gathers, fused_messages = _level_plan_parts(
+        ring, group_statics
+    )
 
     def level_plan(groups_args):
         return lfn(groups_args)
@@ -1060,6 +1168,7 @@ def _build_level_plan(ring: sr.Semiring, group_statics: tuple) -> _Plan:
         fn=jax.jit(level_plan),
         uses_kernel=any(group_kernel),
         group_kernel=group_kernel,
+        group_gathers=group_gathers,
         fused_messages=fused_messages,
     )
 
@@ -1083,7 +1192,9 @@ def _build_sharded_level_plan(
         for (rel_attrs, doms, in_canon, pred_attrs, out_canon, n, member_dims)
         in group_statics
     )
-    lfn, group_kernel, fused_messages = _level_plan_parts(ring, local_statics)
+    lfn, group_kernel, group_gathers, fused_messages = _level_plan_parts(
+        ring, local_statics
+    )
     collective = dist.ring_collective(ring)
     assert collective is not None, "caller gates on ring_collective"
 
@@ -1109,6 +1220,7 @@ def _build_sharded_level_plan(
         fn=jax.jit(sharded_level_plan),
         uses_kernel=any(group_kernel),
         group_kernel=group_kernel,
+        group_gathers=group_gathers,
         fused_messages=fused_messages,
         sharded=True,
         allreduce_bytes=bytes_,
@@ -1289,10 +1401,16 @@ class PlanCache:
             self.stats.kernel_execs += 1
         else:
             self.stats.fallback_execs += 1
+        self._account_gathers(entry.gathers)
         if stats is not None:
             stats.plan_traces += int(traced)
             stats.plan_hits += int(not traced)
             stats.kernel_execs += int(entry.uses_kernel)
+
+    def _account_gathers(self, gathers: tuple[int, int]) -> None:
+        """One member's rowwise gathers: (one-hot, take)."""
+        self.stats.onehot_gathers += gathers[0]
+        self.stats.take_gathers += gathers[1]
 
     @property
     def plan_shards(self) -> int:
@@ -1493,6 +1611,7 @@ class PlanCache:
                     self.stats.kernel_execs += 1
                 else:
                     self.stats.fallback_execs += 1
+                self._account_gathers(entry.group_gathers[pos])
                 if stats is not None:
                     stats.plan_traces += int(traced)
                     stats.plan_hits += int(not traced)

@@ -36,33 +36,40 @@ def _tiles(n: int, g: int) -> tuple[int, int]:
     return min(DEFAULT_TN, _round_up(n, 128)), min(DEFAULT_TG, _round_up(g, 8))
 
 
-@partial(jax.jit, static_argnames=("num_segments", "op", "interpret"))
+@partial(jax.jit, static_argnames=("num_segments", "op", "interpret", "lane_major"))
 def aggregate_op(codes, values, num_segments: int, op: str = "sum",
-                 interpret: bool | None = None):
-    """(G, V) ⊕-aggregate of an (N, V) slab (or (G,) of an (N,) vector)."""
+                 interpret: bool | None = None, lane_major: bool = False):
+    """(G, V) ⊕-aggregate of an (N, V) slab (or (G,) of an (N,) vector).
+
+    ``lane_major``: the slab is (V, N), rows on the lanes as the kernel
+    takes them, and goes in without a transpose."""
     n = codes.shape[0]
     squeeze = values.ndim == 1
     if squeeze:
-        values = values[:, None]
+        values = values[None, :]
+    elif not lane_major:
+        values = values.T
     tn, tg = _tiles(n, num_segments)
     pad_n = (-n) % tn
     pad_g = (-num_segments) % tg
     # pad rows carry code -1, which matches no segment
     codes = jnp.pad(codes.astype(jnp.int32), (0, pad_n), constant_values=-1)
-    values = jnp.pad(values.astype(jnp.float32), ((0, pad_n), (0, 0)))
+    values = jnp.pad(values.astype(jnp.float32), ((0, 0), (0, pad_n)))
     out = segment_aggregate(
-        codes[None, :], values.T, num_segments + pad_g, op=op, tn=tn, tg=tg,
+        codes[None, :], values, num_segments + pad_g, op=op, tn=tn, tg=tg,
         interpret=interpret,
     )[:num_segments]
     return out[:, 0] if squeeze else out
 
 
-def level_aggregate(items, op: str = "sum", interpret: bool | None = None):
+def level_aggregate(items, op: str = "sum", interpret: bool | None = None,
+                    lane_major: bool = False):
     """Fuse several independent ``(codes, values, num_segments)`` segment
     reductions into ONE ``level_segment_aggregate`` launch.
 
     Each item j is one same-level message: ``codes`` (n_j,) int32 local
-    segment ids in [0, g_j), ``values`` (n_j, v_j) row slab.  Rows are padded
+    segment ids in [0, g_j), ``values`` (n_j, v_j) row slab, or (v_j, n_j)
+    with ``lane_major`` (rows on the lanes, no transpose).  Rows are padded
     to the tile multiple with code -1 (matches no segment), columns to the
     common width and segments to the tile multiple with the ⊕-identity; local
     ids shift by the running segment offset so the concatenated launch is
@@ -73,7 +80,9 @@ def level_aggregate(items, op: str = "sum", interpret: bool | None = None):
     """
     assert items, "level_aggregate of zero messages"
     ident = IDENTITY[op]
-    v_max = max(v.shape[1] for _, v, _ in items)
+    if not lane_major:
+        items = [(c, v.T, g) for c, v, g in items]
+    v_max = max(v.shape[0] for _, v, _ in items)
     tn, tg = _tiles(max(c.shape[0] for c, _, _ in items),
                     max(g for _, _, g in items))
     all_codes, all_vals = [], []
@@ -87,14 +96,14 @@ def level_aggregate(items, op: str = "sum", interpret: bool | None = None):
         codes = codes.astype(jnp.int32) + seg_off
         if pad_n:
             codes = jnp.concatenate([codes, jnp.full((pad_n,), -1, jnp.int32)])
-        if values.shape[1] < v_max or pad_n:
+        if values.shape[0] < v_max or pad_n:
             values = jnp.pad(
                 values,
-                ((0, pad_n), (0, v_max - values.shape[1])),
+                ((0, v_max - values.shape[0]), (0, pad_n)),
                 constant_values=ident,
             )
         all_codes.append(codes)
-        all_vals.append(values.astype(jnp.float32).T)
+        all_vals.append(values.astype(jnp.float32))
         n_blocks = (n + pad_n) // tn
         g_blocks = (g + pad_g) // tg
         for s in range(g_blocks):
@@ -120,7 +129,7 @@ def level_aggregate(items, op: str = "sum", interpret: bool | None = None):
         interpret=interpret,
     )
     return [
-        out[off : off + g, : v.shape[1]]
+        out[off : off + g, : v.shape[0]]
         for (off, g), (_, v, _) in zip(spans, items)
     ]
 

@@ -402,3 +402,136 @@ def test_env_gates_use_plans_and_batch_fanout(monkeypatch):
     # explicit arguments always win over the env
     t = Treant(cat, ring=sr.SUM, use_plans=False, batch_fanout=False)
     assert t.engine.plans is None and not t.batch_fanout
+
+
+# ---------------------------------------------------------------------------
+# lane-dense rowwise stage ≡ the row-major ``take`` path: vmapped and fused
+# ---------------------------------------------------------------------------
+
+from test_plans import (  # noqa: E402 — the plan-level take reference
+    LANE_DOMS,
+    LANE_IN,
+    LANE_OUT,
+    LANE_REL,
+    LANE_RINGS,
+    assert_lane_equal,
+    lane_args,
+    lane_device,
+    lane_member,
+    lane_rows,
+    take_reference,
+)
+
+# members' carried γ sizes: the group pads x and y to the widest
+LANE_MEMBERS = ({"x": 6, "y": 4}, {"x": 3, "y": 2}, {"x": 5, "y": 4})
+
+
+def lane_group(ring_name, codes, sigma, width, seed):
+    """``width`` members' host inputs, device arguments and take references."""
+    dims = [dict(LANE_DOMS, **md) for md in LANE_MEMBERS[:width]]
+    members = [lane_member(ring_name, codes, d, sigma, seed + i) for i, d in enumerate(dims)]
+    dev = [lane_device(*mb) for mb in members]
+    in_idx, pred_codes, seg = lane_args(codes, sigma)
+    args = (tuple(d[0] for d in dev), tuple(d[1] for d in dev), in_idx,
+            tuple(d[2] for d in dev), pred_codes, seg)
+    wants = [take_reference(ring_name, codes, d, mb[0], mb[1], sigma, mb[2])
+             for d, mb in zip(dims, members)]
+    return args, wants
+
+
+@pytest.mark.parametrize("sigma", [(), ("b",)])
+@pytest.mark.parametrize("width", [2, 3])
+@pytest.mark.parametrize("ring_name", sorted(LANE_RINGS))
+def test_lane_dense_batched_plan_matches_take(ring_name, width, sigma):
+    """B = 2 and 3 vmapped members with padded γ: every intermediate is
+    (B, *cells, N), and each member's slice equals its take reference."""
+    from repro.core import plans as plans_mod
+
+    n = 1024
+    codes = lane_rows(n, seed=width)
+    args, wants = lane_group(ring_name, codes, sigma, width, seed=10)
+    plan = plans_mod._build_batched_sparse_plan(
+        LANE_RINGS[ring_name], LANE_REL, LANE_DOMS, LANE_IN, sigma, LANE_OUT, n,
+        LANE_MEMBERS[:width],
+    )
+    outs = plan.fn(*args)
+    assert len(outs) == width
+    for out, want in zip(outs, wants):
+        assert_lane_equal(out, want)
+    # vmapped, every gather is wider than one column: σ masks go one-hot in
+    # every ring, messages only where 0̄ is 0 (the tropical 0̄ is -inf)
+    onehot, take = plan.gathers
+    assert onehot + take == 3 + len(sigma)
+    assert take == (3 if ring_name == "tropical_max" else 0)
+
+
+@pytest.mark.parametrize("ring_name", sorted(LANE_RINGS))
+def test_lane_dense_level_plan_matches_take(ring_name):
+    """A level-fused plan of a two-member group and a one-member group with
+    σ: both hand lane-major slabs to one fused segment reduction."""
+    from repro.core import plans as plans_mod
+
+    n = 1024
+    codes = lane_rows(n, seed=21)
+    ring = LANE_RINGS[ring_name]
+    args2, wants2 = lane_group(ring_name, codes, (), 2, seed=22)
+    args1, wants1 = lane_group(ring_name, codes, ("c",), 1, seed=24)
+    statics = (
+        (LANE_REL, LANE_DOMS, LANE_IN, (), LANE_OUT, n, LANE_MEMBERS[:2]),
+        (LANE_REL, LANE_DOMS, LANE_IN, ("c",), LANE_OUT, n, LANE_MEMBERS[:1]),
+    )
+    plan = plans_mod._build_level_plan(ring, statics)
+    assert plan.fused_messages == 3
+    outs = plan.fn((args2, args1))
+    for got, wants in zip(outs, (wants2, wants1)):
+        for out, want in zip(got, wants):
+            assert_lane_equal(out, want)
+    assert len(plan.group_gathers) == 2
+
+
+def test_rowwise_gather_counters_per_member():
+    """``PlanStats`` counts each executed member's gathers from its plan's
+    static count, as (one-hot, take): the same queries over the tropical
+    ring, whose messages hold 0̄ = -inf, move the message gathers of the
+    vmapped members from one-hot to take."""
+    cat = star_catalog(seed=31)
+    jt = jt_from_catalog(cat)
+    counts = {}
+    for ring_name in ("sum", "tropical_max"):
+        base = Query.make(cat, ring=ring_name, measure=("F", "m"), group_by=("c",))
+        qs = [base.with_predicate(mask_in(5, [i], attr="d")) for i in range(3)]
+        eng = CJTEngine(jt, cat, RINGS[ring_name], use_plans=True)
+        eng.execute_many(qs)
+        st = eng.plans.stats
+        assert st.batched_absorptions == 3
+        counts[ring_name] = (st.onehot_gathers, st.take_gathers)
+    (o_sum, t_sum), (o_max, t_max) = counts["sum"], counts["tropical_max"]
+    assert o_sum + t_sum == o_max + t_max
+    assert o_sum >= 3 and o_max == 0 and t_max == t_sum + o_sum
+
+
+def test_rowwise_gather_counters_in_cache_stats():
+    cat = star_catalog(seed=31)
+    t = Treant(cat, ring=sr.SUM, jt=jt_from_catalog(cat), use_plans=True)
+    sess = t.open_session(star_spec(), name="s")
+    sess.apply(SetFilter("a", values=(0, 1), source="by_a"))
+    plans = t.cache_stats()["plans"]
+    assert plans["batched_absorptions"] > 0 and plans["onehot_gathers"] > 0
+    assert plans["take_gathers"] >= 0
+
+
+def test_rowwise_onehot_pct_reads_the_gather_counters():
+    """The benchmark's reader: one-hot share of the window's gathers, and
+    nothing for a program without the counters or with no gathers."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "metrics" / "rowwise_onehot_pct.py"
+    spec = importlib.util.spec_from_file_location("rowwise_onehot_pct", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    counters = {"plans.onehot_gathers": 30, "plans.take_gathers": 10}
+    assert reader.read({"counters": counters}) == 75.0
+    assert reader.read({"counters": {"plans.plan_hits": 5}}) is None
+    assert reader.read({"counters": {"plans.onehot_gathers": 0,
+                                     "plans.take_gathers": 0}}) is None

@@ -315,3 +315,194 @@ def test_catalog_dev_codes_cached_and_lru_bounded():
     for attrs in [("a",), ("b",), ("a", "b")]:
         cat.dev_flat_codes(rel, attrs)
     assert len(cat._dev_codes) <= 2
+
+
+# ---------------------------------------------------------------------------
+# lane-dense rowwise stage ≡ the row-major ``take`` path, plan by plan
+# ---------------------------------------------------------------------------
+
+# fact attrs a, b, c; carried γ x, y.  The messages gather at one code (a), a
+# flat two-attr code (b, c: 70 entries, over the one-column take limit) and
+# c; the plans below are built from these statics directly, so every plan
+# kind meets the same contraction.
+LANE_RINGS = {"sum": sr.SUM, "count": sr.COUNT, "tropical_max": sr.TROPICAL_MAX}
+LANE_REL = ("a", "b", "c")
+LANE_DOMS = {"a": 13, "b": 7, "c": 10, "x": 6, "y": 4}
+LANE_IN = (("a", "x"), ("b", "c"), ("c", "y"))
+LANE_OUT = ("x", "a", "y")
+
+
+def lane_rows(n: int, seed: int) -> dict:
+    rng = np.random.default_rng(seed)
+    return {a: rng.integers(0, LANE_DOMS[a], n).astype(np.int32) for a in LANE_REL}
+
+
+def lane_member(ring_name: str, codes: dict, dims: dict, sigma: tuple, seed: int):
+    """One member's (vals, fields, masks) as host arrays: small integers, so
+    f32 ⊗ and ⊕ are exact in any order; tropical messages hold 0̄ (-inf)."""
+    rng = np.random.default_rng(seed)
+    n = len(codes["a"])
+    vals = (np.ones(n, np.float32) if ring_name == "count"
+            else rng.integers(0, 8, n).astype(np.float32))
+    fields = []
+    for m in LANE_IN:
+        f = rng.integers(0, 4, [dims[a] for a in m]).astype(np.float32)
+        if ring_name == "tropical_max":
+            f[rng.random(f.shape) < 0.2] = -np.inf
+        fields.append(f)
+    masks = [rng.random(LANE_DOMS[a]) < 0.6 for a in sigma]
+    return vals, fields, masks
+
+
+def take_reference(ring_name: str, codes: dict, dims: dict, vals, fields, sigma, masks):
+    """The contraction in numpy, gathering row-major with ``take`` as the
+    plans did before their rowwise stage went lane-major: (x, a, y)."""
+    tropical = ring_name == "tropical_max"
+    mul = np.add if tropical else np.multiply
+    zero = np.float32(-np.inf if tropical else 0.0)
+    out = vals[:, None, None]
+    out = mul(out, np.take(fields[0], codes["a"], axis=0)[:, :, None])
+    flat = codes["b"] * LANE_DOMS["c"] + codes["c"]
+    out = mul(out, np.take(fields[1].reshape(-1), flat)[:, None, None])
+    out = mul(out, np.take(fields[2], codes["c"], axis=0)[:, None, :])
+    keep = np.ones(len(vals), bool)
+    for a, m in zip(sigma, masks):
+        keep &= m[codes[a]]
+    out = np.where(keep[:, None, None], out, zero)
+    res = np.full((LANE_DOMS["a"], dims["x"], dims["y"]), zero, np.float32)
+    (np.maximum if tropical else np.add).at(res, codes["a"], out)
+    return res.transpose(1, 0, 2)
+
+
+def lane_args(codes: dict, sigma: tuple):
+    """The shared row-major arguments: (in_idx, pred_codes, seg_idx)."""
+    flat = codes["b"] * LANE_DOMS["c"] + codes["c"]
+    in_idx = tuple(jnp.asarray(c) for c in (codes["a"], flat, codes["c"]))
+    pred_codes = tuple(jnp.asarray(codes[a]) for a in sigma)
+    return in_idx, pred_codes, jnp.asarray(codes["a"])
+
+
+def lane_device(vals, fields, masks):
+    return (jnp.asarray(vals), tuple(jnp.asarray(f) for f in fields),
+            tuple(jnp.asarray(m) for m in masks))
+
+
+def assert_lane_equal(fact: Factor, want: np.ndarray):
+    assert fact.attrs == LANE_OUT
+    np.testing.assert_array_equal(np.asarray(fact.field), want)
+
+
+@pytest.fixture(params=["kernel", "lax"])
+def reduce_path(request, monkeypatch):
+    """Route the segment reduction to the (interpreted) kernel or the lax
+    path, which takes the lane-major slab transposed once."""
+    cost = "1099511627776" if request.param == "kernel" else "0"
+    monkeypatch.setenv("REPRO_PLAN_KERNEL_COST", cost)
+    return request.param
+
+
+@pytest.mark.parametrize("sigma", [(), ("b", "c")])
+@pytest.mark.parametrize("ring_name", sorted(LANE_RINGS))
+def test_lane_dense_scalar_plan_matches_take(ring_name, sigma, reduce_path):
+    from repro.core import plans as plans_mod
+
+    n = 1024
+    codes = lane_rows(n, seed=1)
+    vals, fields, masks = lane_member(ring_name, codes, LANE_DOMS, sigma, seed=2)
+    plan = plans_mod._build_sparse_plan(
+        LANE_RINGS[ring_name], LANE_REL, LANE_DOMS, LANE_IN, sigma, LANE_OUT, n
+    )
+    assert plan.uses_kernel == (reduce_path == "kernel")
+    in_idx, pred_codes, seg = lane_args(codes, sigma)
+    v, f, m = lane_device(vals, fields, masks)
+    out = plan.fn(v, f, in_idx, m, pred_codes, seg)
+    assert_lane_equal(out, take_reference(ring_name, codes, LANE_DOMS, vals, fields,
+                                          sigma, masks))
+    # one member: the σ masks are single columns of at most 64 entries and
+    # stay on take; the messages go one-hot where the ring's 0̄ is 0
+    if ring_name == "tropical_max":
+        assert plan.gathers == (0, 3 + len(sigma))
+    else:
+        assert plan.gathers == (3, len(sigma))
+
+
+@pytest.mark.parametrize("ring_name", sorted(LANE_RINGS))
+def test_lane_dense_row_blocked_plan_matches_take(ring_name, monkeypatch):
+    from repro.core import plans as plans_mod
+
+    monkeypatch.setattr(plans_mod, "ROW_SLAB_BYTES", 1 << 12)
+    monkeypatch.setattr(plans_mod, "_MIN_BLOCK_ROWS", 64)
+    n, sigma = 2048, ("b",)
+    ring = LANE_RINGS[ring_name]
+    _, meta = plans_mod._sparse_fn(ring, LANE_REL, LANE_DOMS, LANE_IN, sigma, LANE_OUT, n)
+    assert plans_mod._row_blocks(n, meta.row_bytes) > 1
+    codes = lane_rows(n, seed=3)
+    vals, fields, masks = lane_member(ring_name, codes, LANE_DOMS, sigma, seed=4)
+    plan = plans_mod._build_sparse_plan(ring, LANE_REL, LANE_DOMS, LANE_IN, sigma,
+                                        LANE_OUT, n)
+    in_idx, pred_codes, seg = lane_args(codes, sigma)
+    v, f, m = lane_device(vals, fields, masks)
+    out = plan.fn(v, f, in_idx, m, pred_codes, seg)
+    assert_lane_equal(out, take_reference(ring_name, codes, LANE_DOMS, vals, fields,
+                                          sigma, masks))
+
+
+SHARDED_SCRIPT = """
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+sys.path.insert(0, {tests!r})
+import test_plans as T
+from repro.core import distributed as dist, plans as P
+
+mesh = dist.make_engine_mesh(2)
+n, checked = 1024, 0
+for ring_name in sorted(T.LANE_RINGS):
+    ring = T.LANE_RINGS[ring_name]
+    for sigma in ((), ("b", "c")):
+        codes = T.lane_rows(n, seed=5)
+        in_idx, pred_codes, seg = T.lane_args(codes, sigma)
+        one = T.lane_member(ring_name, codes, T.LANE_DOMS, sigma, seed=6)
+        plan = P._build_sharded_sparse_plan(ring, T.LANE_REL, T.LANE_DOMS, T.LANE_IN,
+                                            sigma, T.LANE_OUT, n, mesh, dist.SHARD_AXIS)
+        v, f, m = T.lane_device(*one)
+        T.assert_lane_equal(plan.fn(v, f, in_idx, m, pred_codes, seg),
+                            T.take_reference(ring_name, codes, T.LANE_DOMS, *one[:2],
+                                             sigma, one[2]))
+        dims = [dict(T.LANE_DOMS, x=6, y=4), dict(T.LANE_DOMS, x=3, y=2)]
+        members = [T.lane_member(ring_name, codes, d, sigma, seed=7 + i)
+                   for i, d in enumerate(dims)]
+        bplan = P._build_sharded_batched_sparse_plan(
+            ring, T.LANE_REL, T.LANE_DOMS, T.LANE_IN, sigma, T.LANE_OUT, n,
+            tuple({{"x": d["x"], "y": d["y"]}} for d in dims), mesh, dist.SHARD_AXIS)
+        dev = [T.lane_device(*mb) for mb in members]
+        outs = bplan.fn(tuple(d[0] for d in dev), tuple(d[1] for d in dev), in_idx,
+                        tuple(d[2] for d in dev), pred_codes, seg)
+        for out, d, mb in zip(outs, dims, members):
+            T.assert_lane_equal(out, T.take_reference(ring_name, codes, d, *mb[:2],
+                                                      sigma, mb[2]))
+        checked += 1 + len(outs)
+print(json.dumps({{"checked": checked}}))
+"""
+
+
+def test_lane_dense_two_shard_plans_match_take():
+    """Row-sharded scalar and batched plans over a 2-device mesh: each shard
+    runs the lane-major rowwise stage on its row block, the rows axis stays
+    minor, and the ⊕-all-reduced factors equal the take reference.  Runs in
+    a subprocess with 2 virtual devices, like tests/test_distributed.py."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    here = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.pathsep.join([str(here.parent / "src"), str(here)]))
+    out = subprocess.run(
+        [sys.executable, "-c", SHARDED_SCRIPT.format(tests=str(here))],
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert json.loads(out.stdout.strip().splitlines()[-1])["checked"] == 3 * 2 * 3

@@ -13,6 +13,8 @@ the persistent compilation cache is off around the compiles, since entries
 compiled for an absent chip cannot be read back.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -141,3 +143,74 @@ def test_sharded_level_plan_compiles(topo, as_tpu_process):
 
     compiled = _compile(plan.fn, (group(365), group(400)))
     assert "all-reduce" in compiled.as_text()
+
+
+_SHAPE = re.compile(r"(f32|s32|pred)\[([0-9,]*)\]\{([0-9,]*)(?::([^}]*))?\}")
+
+
+def _tiled_bytes(dims: str, layout: str, tiling: str | None, itemsize: int) -> tuple:
+    """(dense, tiled) bytes of an HLO array shape: the minor dims of its
+    layout rounded up to the tile, as the chip stores it in memory."""
+    shape = [int(x) for x in dims.split(",")] if dims else []
+    tiled = list(shape)
+    tile = re.search(r"T\(([0-9,]+)\)", tiling or "")
+    if tile and shape:
+        minor_to_major = [int(x) for x in layout.split(",")]
+        for dim, t in zip(minor_to_major, reversed([int(x) for x in tile.group(1).split(",")])):
+            tiled[dim] = -(-tiled[dim] // t) * t
+    return int(np.prod(shape)) * itemsize, int(np.prod(tiled)) * itemsize
+
+
+def _written(hlo: str, scope: str):
+    """(shape, op line) of each instruction under ``scope`` that writes an
+    array: every instruction outside the bodies of fusions, which keep
+    their intermediates in registers."""
+    fused = {m.group(1) for m in re.finditer(r" fusion\(.*calls=(%[\w.\-]+)", hlo)}
+    comp = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line.rstrip())
+        if head:
+            comp = head.group(1)
+            continue
+        # the scope as named, or vmapped: .../rowwise/... or .../vmap(rowwise)/...
+        if comp in fused or not re.search(rf"[/(]{scope}[/)]", line) or " = " not in line:
+            continue
+        shape = _SHAPE.match(line.split(" = ", 1)[1])
+        if shape:
+            yield shape, line
+
+
+def test_taxi_batched_rowwise_is_lane_dense(one_chip, as_tpu_process):
+    """The taxi-shaped vmapped absorption (B = 2 over 2^23 rows, messages
+    (pu_zone, ·0 = 7), (do_zone,) and (day,), σ on hour) keeps its rowwise
+    stage lane-dense: no f32 array it writes is padded to over twice its
+    values, and no (D, N) one-hot of a gather is written."""
+    rel = ("pu_zone", "do_zone", "day", "hour", "payment_type", "rate_code")
+    doms = {"pu_zone": 265, "do_zone": 265, "day": 31, "hour": 24,
+            "payment_type": 6, "rate_code": 7, "·0": 7}
+    in_canon = (("pu_zone", "·0"), ("do_zone",), ("day",))
+    members = ({"·0": 7}, {"·0": 7})
+    plan = plans_mod._build_batched_sparse_plan(
+        sr.SUM, rel, doms, in_canon, ("hour",), ("·0",), ROWS, members
+    )
+    assert plan.gathers == (4, 0)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = sds((ROWS,), jnp.int32)
+    fields = (sds((265, 7)), sds((265,)), sds((31,)))
+    hlo = _compile(
+        plan.fn,
+        (sds((ROWS,)),) * 2, (fields,) * 2, (rows,) * 3,
+        ((sds((24,), jnp.bool_),),) * 2, (rows,), rows,
+    ).as_text()
+    written = list(_written(hlo, "rowwise"))
+    assert any(s.group(1) == "f32" for s, _ in written)
+    for shape, line in written:
+        dims = [int(x) for x in shape.group(2).split(",") if x]
+        assert 265 not in dims, line
+        if shape.group(1) != "f32":
+            continue
+        dense, tiled = _tiled_bytes(*shape.groups()[1:], 4)
+        assert tiled <= 2 * dense, line
