@@ -1,4 +1,7 @@
-"""Share of the traced window in which no operation ran on the device."""
+"""Share of the traced window, from the start of the device's first
+operation to the end of its last, in which no operation ran on the device.
+The profiler's start and stop latency before and after (the trace's
+``edges``) is left out."""
 
 
 def read(w):

@@ -1,7 +1,7 @@
 """The program's own scopes and spans in a profiler trace.
 
 ``bench/trace_reduce.py`` names device time by HLO operation and idle gaps
-by the benchmark's own host spans.  This module reads, from the same
+by the innermost host span.  This module reads, from the same
 ``.xplane.pb``, what the program marks itself (``src/repro/trace.py``):
 
 - scopes: device seconds, own time as ``trace_reduce`` counts ``ops``, by
@@ -14,10 +14,9 @@ by the benchmark's own host spans.  This module reads, from the same
   reader of the protobuf wire format.
 - spans: host seconds and counts by ``treant.*`` span name, any TraceMe
   ``#k=v#`` suffix stripped.
-- gaps: device idle seconds by the innermost host span open at the middle of
-  each gap, the program's ``treant.*`` spans among the benchmark's own.
-- edges: device idle seconds before the first and after the last device
-  operation of the profile's window.
+- reduce_bytes: by ``segment_reduce_<op>`` scope, the bytes its segment
+  reductions must move (``trace_reduce.reduction_bytes``), whether the
+  Pallas kernel or XLA reduced.
 
 A program that marks nothing reads as all ``unscoped`` and no spans.
 """
@@ -35,7 +34,6 @@ from bench import trace_reduce
 ROOT = Path(__file__).resolve().parents[1]
 TRACE_DIR = ROOT / ".bench_trace"  # where bench/run.py profiles a --trace 1 run
 SPAN_PREFIX = "treant."
-HOST_SPANS = trace_reduce.HOST_SPANS + (SPAN_PREFIX,)
 SCOPES = frozenset({"rowwise", "finalize", "batch_stage", "batch_slice", "dense_contract",
                     "cube_slice", "row_blocks"})
 REDUCE_SCOPE = "segment_reduce_"
@@ -154,98 +152,38 @@ def scope_of(tf_op: str | None) -> str:
     return UNSCOPED
 
 
-def span_name(name: str) -> str:
-    """A host span's name without a TraceMe ``#k=v,...#`` suffix."""
-    return name.split("#", 1)[0]
-
-
 # -- the reduction --------------------------------------------------------------------
-def host_spans(data: ProfileData) -> list[tuple[str, int, int]]:
-    """``(name, start ns, end ns)`` of every host span of the benchmark's
-    and the program's, names without a TraceMe suffix."""
-    return [(span_name(ev.name), ev.start_ns, ev.start_ns + ev.duration_ns)
-            for plane in data.planes if plane.name.startswith("/host:")
-            for line in plane.lines for ev in line.events
-            if ev.name.startswith(HOST_SPANS)]
-
-
 def reduce(path: str) -> dict:
-    """Scopes, spans, gaps and edges of one trace file (module docstring)."""
+    """Scopes, spans and reduction bytes of one trace file (module docstring)."""
     data = ProfileData.from_file(path)
     paths = tf_ops(path)
-    host = host_spans(data)
-    devices, env = [], {}
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            lines = [ln for ln in plane.lines if ln.name == "XLA Ops"]
-            if lines:
-                events = list(lines[0].events)
-                raw = paths.get(plane.name, [])
-                if [name for name, _ in raw] == [ev.name for ev in events]:
-                    ops = [op for _, op in raw]
-                else:  # not in file order: match by name
-                    by_name = dict(reversed(raw))
-                    ops = [by_name.get(ev.name) for ev in events]
-                devices.append(list(zip(events, ops)))
-        elif plane.name == "Task Environment":
-            env = dict(plane.stats)
-    if "profile_start_time" in env and "profile_stop_time" in env:
-        w0, w1 = 0.0, float(env["profile_stop_time"] - env["profile_start_time"])
-    elif host:
-        w0, w1 = min(s for _, s, _ in host), max(e for _, _, e in host)
-    else:
-        w0 = w1 = 0.0
-
     spans: dict[str, dict] = {}
-    for name, s, e in host:
+    for name, s, e in trace_reduce.host_spans(data):
         if name.startswith(SPAN_PREFIX):
             acc = spans.setdefault(name, {"seconds": 0.0, "count": 0})
             acc["seconds"] += (e - s) / 1e9
             acc["count"] += 1
-
     scopes: dict[str, float] = {}
     scope_ops: dict[str, dict[str, float]] = {}
-    gaps: dict[str, float] = {}
-    edges = {"start": 0.0, "stop": 0.0}
-    for events in devices:
-        # own time: an operation's duration less that of the operations
-        # nested in it, as trace_reduce counts it
-        evs = sorted(events, key=lambda x: (x[0].start_ns, -x[0].duration_ns))
-        own = [ev.duration_ns for ev, _ in evs]
-        stack: list[tuple[float, int]] = []
-        intervals = []
-        for i, (ev, _) in enumerate(evs):
-            s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-            intervals.append((s, e))
-            while stack and stack[-1][0] <= s:
-                stack.pop()
-            if stack:
-                own[stack[-1][1]] -= ev.duration_ns
-            stack.append((e, i))
-        if not intervals:
-            continue
-        for (ev, op), t in zip(evs, own):
-            key, label = scope_of(op), trace_reduce.op_label(ev.name)
-            scopes[key] = scopes.get(key, 0.0) + max(t, 0.0) / 1e9
+    reduce_bytes: dict[str, int] = {}
+    for plane, events in trace_reduce.device_events(data).items():
+        raw = paths.get(plane, [])
+        if [name for name, _ in raw] == [ev.name for ev in events]:
+            ops = [op for _, op in raw]
+        else:  # not in file order: match by name
+            by_name = dict(reversed(raw))
+            ops = [by_name.get(ev.name) for ev in events]
+        for i, t in trace_reduce.own_times(events):
+            key, label = scope_of(ops[i]), trace_reduce.op_label(events[i].name)
+            scopes[key] = scopes.get(key, 0.0) + t / 1e9
             per = scope_ops.setdefault(key, {})
-            per[label] = per.get(label, 0.0) + max(t, 0.0) / 1e9
-        lo, hi = (w0, w1) if w1 > w0 else (min(s for s, _ in intervals),
-                                            max(e for _, e in intervals))
-        busy = trace_reduce._union([(max(s, lo), min(e, hi)) for s, e in intervals
-                                    if e > lo and s < hi])
-        if not busy:
-            continue
-        edges["start"] += (busy[0][0] - lo) / 1e9 / len(devices)
-        edges["stop"] += (hi - busy[-1][1]) / 1e9 / len(devices)
-        bounds = [lo] + [x for iv in busy for x in iv] + [hi]
-        for a, b in zip(bounds[0::2], bounds[1::2]):
-            if b > a:
-                mid = (a + b) / 2
-                open_ = [(s, name) for name, s, e in host if s <= mid < e]
-                label = max(open_)[1] if open_ else "none"
-                gaps[label] = gaps.get(label, 0.0) + (b - a) / 1e9 / len(devices)
-    return {"scopes": scopes, "scope_ops": scope_ops, "spans": spans, "gaps": gaps,
-            "edges": edges}
+            per[label] = per.get(label, 0.0) + t / 1e9
+            moved = trace_reduce.reduction_bytes(events[i].name, ops[i]) \
+                if key.startswith(REDUCE_SCOPE) else None
+            if moved is not None:
+                reduce_bytes[key] = reduce_bytes.get(key, 0) + moved
+    return {"scopes": scopes, "scope_ops": scope_ops, "spans": spans,
+            "reduce_bytes": reduce_bytes}
 
 
 @functools.lru_cache(maxsize=4)

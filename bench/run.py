@@ -200,10 +200,11 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, peaks: dict | N
     if trace and loop.traced is not None:
         ta, tb = loop.traced
         traced_events = sum(1 for r in done if ta <= r.done <= tb)
-        kernels = tuple(k for _, mod in cell["readers"].values() for k in getattr(mod, "KERNELS", ()))
-        reduced = trace_reduce.reduce(trace_reduce.find(str(TRACE_DIR)), tuple(dict.fromkeys(kernels)))
-        log(f"traced {tb - ta!r} s host, {reduced['window_s']!r} s profile, "
-            f"{traced_events} events, {len(reduced['calls'])} kernel calls")
+        reduced = trace_reduce.reduce(trace_reduce.find(str(TRACE_DIR)))
+        log(f"traced {tb - ta!r} s host, {reduced['profile_s']!r} s profile, "
+            f"{reduced['window_s']!r} s from the first device operation to the last "
+            f"(edges {reduced['edges']['start']!r} s and {reduced['edges']['stop']!r} s), "
+            f"{traced_events} events")
     w = {
         "counters": delta, "events": len(done), "compiles": compiled,
         "rerendered": sum(len(r.routes) for r in done),

@@ -12,4 +12,6 @@ def test_flight_crossfilter_matches_reference_and_control_fails(monkeypatch):
     assert r["results"] > 0
     assert r["control_max_rel_err"] > out["check"]["max_rel_err"]["limit"], r
     assert out["per_layer"]["serve_batch_width"] >= 1
+    # every per-layer reader runs, whichever cells its metric lists
+    assert set(out["per_layer"]) == {m["name"] for m in tiny.SPEC["per_layer"]}
     assert set(out["end_to_end"]) == {"setup_s", "event_p50_ms", "events_per_s"}

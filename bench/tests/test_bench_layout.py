@@ -19,20 +19,28 @@ SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
-@pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
-def test_loader_finds_every_piece_of_a_cell_by_name(workload):
-    cell = run.load_cell(workload)
+def _loads_by_name(workload, spec):
+    """``bench/run.py``'s loader finds every piece of the cell by name, and
+    the readers of the metrics whose ``workloads`` list names it."""
+    cell = run.load_cell(workload, spec)
+    assert cell["workload"]["name"] == workload
     assert cell["config"]["name"] == cell["workload"]["config"]
     assert cell["mix"]["name"] == cell["workload"]["traffic"]
-    assert set(cell["readers"]) == {m["name"] for m in SPEC["per_layer"]
+    assert set(cell["readers"]) == {m["name"] for m in spec["per_layer"]
                                     if workload in m.get("workloads", [workload])}
     for _, mod in cell["readers"].values():
         assert callable(mod.read)
     assert cell["limits"]["max_rel_err"]["limit"] > 0
 
 
-@pytest.mark.parametrize("workload", tiny.CANDIDATES)
+@pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
+def test_loader_finds_every_piece_of_a_cell_by_name(workload):
+    _loads_by_name(workload, SPEC)
+
+
+@pytest.mark.parametrize("workload", tiny.WORKLOADS)
 def test_candidate_loads_like_a_cell_with_every_metric(workload):
+    """On the CPU every cell and candidate loads every per-layer reader."""
     cell = tiny.load(workload)
     assert cell["workload"]["name"] == workload
     assert cell["config"]["name"] == cell["workload"]["config"]
@@ -41,28 +49,63 @@ def test_candidate_loads_like_a_cell_with_every_metric(workload):
     assert cell["limits"]["max_rel_err"]["limit"] > 0
 
 
+def _without(spec, workload):
+    """``spec`` before the change that named the workload: without its
+    ``workloads`` entry, and without its configuration where no other cell
+    uses it."""
+    spec = json.loads(json.dumps(spec))
+    spec["workloads"] = [w for w in spec["workloads"] if w["name"] != workload]
+    used = {w["config"] for w in spec["workloads"]}
+    config, _ = tiny.split(workload)
+    spec["configs"] = [c for c in spec["configs"] if c["name"] != config or config in used]
+    return spec
+
+
+@pytest.mark.parametrize("workload", tiny.WORKLOADS)
+def test_a_cell_joins_by_its_own_entries_alone(monkeypatch, workload):
+    """Named by a ``configs`` and a ``workloads`` entry, no metric's list
+    touched, a cell keeps the spec within the contract, loads on the chip's
+    path and on the CPU's, and runs at the tiny size."""
+    before = _without(SPEC, workload)
+    spec = tiny.named(workload, before)
+    assert spec["per_layer"] == before["per_layer"]
+    assert spec["end_to_end"] == before["end_to_end"]
+    assert len(spec["workloads"]) == len(before["workloads"]) + 1
+    assert len(before["configs"]) <= len(spec["configs"]) <= len(before["configs"]) + 1
+    _keeps_to_the_contract(spec)
+    _loads_by_name(workload, spec)
+    assert set(tiny.load(workload, spec)["readers"]) == {m["name"] for m in spec["per_layer"]}
+    out = tiny.run_tiny(monkeypatch, workload, seconds=0.5, spec=spec)
+    assert out["correct"], out["readings"]
+    assert set(out["per_layer"]) == {m["name"] for m in spec["per_layer"]}
+
+
 def test_unknown_workload_is_refused():
     with pytest.raises(SystemExit):
         run.load_cell("no-such-cell")
 
 
-def test_names_units_and_keys_keep_to_the_contract():
-    assert set(SPEC) == {"command", "paths", "run_seconds", "configs", "workloads",
+def _keeps_to_the_contract(spec):
+    assert set(spec) == {"command", "paths", "run_seconds", "configs", "workloads",
                          "end_to_end", "per_layer"}
     names = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer")
-             for x in SPEC[k]]
+             for x in spec[k]]
     assert len(names) == len(set(names)) and all(NAME.match(n) for n in names)
-    ends = {m["name"] for m in SPEC["end_to_end"]}
+    ends = {m["name"] for m in spec["end_to_end"]}
     assert "setup_s" in ends
-    for m in SPEC["per_layer"]:
+    for m in spec["per_layer"]:
         assert m["moves"] in ends
         assert (ROOT / "bench" / "metrics" / f"{m['name']}.py").is_file()
-    for c in SPEC["configs"]:
+    for c in spec["configs"]:
         assert (ROOT / c["file"]).is_file()
         assert json.loads((ROOT / c["file"]).read_text())["reduced"] == c["reduced"]
-    for w in SPEC["workloads"]:
+    for w in spec["workloads"]:
         assert (ROOT / "bench" / "traffic" / f"{w['traffic']}.json").is_file()
         assert len(w["why"]) <= 200
+
+
+def test_names_units_and_keys_keep_to_the_contract():
+    _keeps_to_the_contract(SPEC)
 
 
 @pytest.mark.parametrize("workload", tiny.WORKLOADS)

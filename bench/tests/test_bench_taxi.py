@@ -13,3 +13,5 @@ def test_taxi_jump_matches_reference_and_control_fails(monkeypatch):
     assert r["control_max_rel_err"] > out["check"]["max_rel_err"]["limit"], r
     # no think time: the queue never empties, every step batches both analysts
     assert out["per_layer"]["serve_batch_width"] == 2
+    # every per-layer reader runs, whichever cells its metric lists
+    assert set(out["per_layer"]) == {m["name"] for m in tiny.SPEC["per_layer"]}

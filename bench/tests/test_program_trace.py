@@ -20,17 +20,16 @@ from bench.tests import tiny
 DATA = Path(__file__).parent / "data"
 OLD = DATA / "segment_reduce.xplane.pb"
 NEW = DATA / "program_spans.xplane.pb"
-KERNELS = ("segment_aggregate_", "level_segment_aggregate_")
 
 
 @pytest.fixture(scope="module")
 def old():
-    return trace_reduce.reduce(str(OLD), KERNELS), program_trace.reduce(str(OLD))
+    return trace_reduce.reduce(str(OLD)), program_trace.reduce(str(OLD))
 
 
 @pytest.fixture(scope="module")
 def new():
-    return trace_reduce.reduce(str(NEW), KERNELS), program_trace.reduce(str(NEW))
+    return trace_reduce.reduce(str(NEW)), program_trace.reduce(str(NEW))
 
 
 @pytest.mark.parametrize("path", [OLD, NEW], ids=["old", "new"])
@@ -51,18 +50,19 @@ def test_a_program_without_scopes_or_spans_reads_as_before(old):
     before, after = old
     assert set(after["scopes"]) == {program_trace.UNSCOPED}
     assert after["scopes"][program_trace.UNSCOPED] == pytest.approx(sum(before["ops"].values()))
-    assert after["spans"] == {}
-    assert after["gaps"] == before["gaps"]
-    assert after["edges"]["start"] + after["edges"]["stop"] <= before["gaps"]["none"] + 1e-9
+    assert after["spans"] == {} and after["reduce_bytes"] == {}
+    assert set(before["gaps"]) == {"bench.wait"}  # named by the benchmark's spans alone
 
 
 def test_old_fixture_reduces_to_the_fields_and_values_it_gave(old):
     before, _ = old
-    assert set(before) == {"window_s", "busy_s", "devices", "ops", "calls", "gaps"}
+    assert set(before) == {"window_s", "busy_s", "profile_s", "edges", "devices", "ops", "gaps"}
     assert before["devices"] == 1
-    assert {c["name"] for c in before["calls"]} == {"segment_aggregate_sum",
-                                                     "level_segment_aggregate_sum"}
-    assert set(before["gaps"]) == {"none", "bench.wait"}
+    assert {"segment_aggregate_sum", "level_segment_aggregate_sum"} <= set(before["ops"])
+    # its idle time outside any span lay before the first and after the last
+    # device operation: the profile's edges, now apart from the gaps
+    assert set(before["gaps"]) == {"bench.wait"}
+    assert before["edges"]["start"] > 0 and before["edges"]["stop"] > 0
     assert sum(before["gaps"].values()) == pytest.approx(
         before["window_s"] - before["busy_s"], rel=1e-6)
 
@@ -78,9 +78,8 @@ def test_both_reductions_land_in_their_scope_copies_included(new):
     assert any(trace_reduce.op_label(n).startswith("copy") for n, _ in kernel_ops)
     for _, op in kernel_ops + fallback_ops:
         assert program_trace.scope_of(op) == "segment_reduce_sum", op
-    kernel = [c for c in before["calls"] if c["kernel"] == "segment_aggregate_"]
-    assert kernel and "segment_aggregate_sum" in after["scope_ops"]["segment_reduce_sum"]
-    assert after["scopes"]["segment_reduce_sum"] > sum(c["seconds"] for c in kernel)
+    assert "segment_aggregate_sum" in after["scope_ops"]["segment_reduce_sum"]
+    assert after["scopes"]["segment_reduce_sum"] > before["ops"]["segment_aggregate_sum"]
     assert after["scopes"]["rowwise"] > 0
     # every device second is under some scope or named unscoped, and the
     # plans' own stages hold nearly all of it
@@ -90,9 +89,9 @@ def test_both_reductions_land_in_their_scope_copies_included(new):
 
 def test_idle_gap_goes_to_the_innermost_program_span(new):
     before, after = new
-    assert set(before["gaps"]) <= {"serve.step", "none"}  # the harness sees only its own
-    assert after["gaps"]["treant.session.derive"] > 0.015  # the 20 ms sleep
-    assert sum(after["gaps"].values()) == pytest.approx(sum(before["gaps"].values()))
+    # the benchmark's own spans and the program's name the gaps by one rule
+    assert before["gaps"]["treant.session.derive"] > 0.015  # the 20 ms sleep
+    assert "serve.step" not in before["gaps"]  # the program's spans lie inside it
     spans = after["spans"]
     assert spans["treant.plans.run"]["count"] == 2
     assert spans["treant.serve.step"]["count"] == 1
@@ -116,8 +115,8 @@ def test_scope_of_takes_the_innermost_scope(tf_op, scope):
 
 
 def test_span_names_lose_their_ids():
-    assert program_trace.span_name("treant.serve.step#batch=3,events=16#") == "treant.serve.step"
-    assert program_trace.span_name("treant.plans.run") == "treant.plans.run"
+    assert trace_reduce.span_name("treant.serve.step#batch=3,events=16#") == "treant.serve.step"
+    assert trace_reduce.span_name("treant.plans.run") == "treant.plans.run"
 
 
 # -- the readers, on hand-made reductions -------------------------------------------------
@@ -133,9 +132,8 @@ def _w(counters=None, traced_events=4):
 PROGRAM = {"scopes": {"rowwise": 0.2, "segment_reduce_sum": 0.05, "segment_reduce_max": 0.01,
                       "finalize": 0.001, "unscoped": 0.01},
            "spans": {"treant.session.derive": {"seconds": 0.008, "count": 16}},
-           "gaps": {}, "scope_ops": {}, "edges": {"start": 0.0, "stop": 0.0}}
-PARENT = {"scopes": {"unscoped": 0.3}, "spans": {}, "gaps": {"none": 0.1}, "scope_ops": {},
-          "edges": {"start": 0.0, "stop": 0.1}}
+           "scope_ops": {}, "reduce_bytes": {"segment_reduce_sum": 12e9, "segment_reduce_max": 3e9}}
+PARENT = {"scopes": {"unscoped": 0.3}, "spans": {}, "scope_ops": {}, "reduce_bytes": {}}
 
 
 @pytest.mark.parametrize("name,program,parent", [
@@ -154,6 +152,19 @@ def test_trace_readers(monkeypatch, name, program, parent):
     assert read(_w()) is None
 
 
+def test_roofline_reader_takes_bytes_and_seconds_under_the_reduction_scopes(monkeypatch):
+    read = _reader("segment_reduce_roofline_pct").read
+    w = dict(_w(), peaks={"hbm_bytes_per_s": 1e12})
+    monkeypatch.setattr(program_trace, "read", lambda w: PROGRAM)
+    # 15 GB at 1 TB/s take 15 ms at least; the scopes took 60 ms
+    assert read(w) == pytest.approx(25.0)
+    for program in (PARENT, dict(PROGRAM, reduce_bytes={})):  # nothing to read
+        monkeypatch.setattr(program_trace, "read", lambda w, p=program: p)
+        assert read(w) is None
+    monkeypatch.setattr(program_trace, "read", lambda w: None)
+    assert read(w) is None
+
+
 def test_queue_reader_reads_the_counter_and_nothing_without_it():
     read = _reader("serve_queue_ms_per_event").read
     assert read(_w({"serve.queue_wait_s": 3.2, "serve.events_processed": 16})) == \
@@ -165,7 +176,7 @@ def test_queue_reader_reads_the_counter_and_nothing_without_it():
 def test_trace_readers_read_nothing_from_an_untraced_run():
     w = dict(_w(), trace=None)
     for name in ("rowwise_ms_per_event", "segment_reduce_scoped_ms_per_event",
-                 "derive_ms_per_event"):
+                 "derive_ms_per_event", "segment_reduce_roofline_pct"):
         assert _reader(name).read(w) is None
 
 
@@ -192,7 +203,7 @@ def test_served_step_and_idle_spans_nest(tmp_path):
         one_step()
         system.server.idle()
     data = ProfileData.from_file(trace_reduce.find(str(tmp_path)))
-    spans = [s for s in program_trace.host_spans(data) if s[0].startswith("treant.")]
+    spans = [s for s in trace_reduce.host_spans(data) if s[0].startswith("treant.")]
     steps = [s for s in spans if s[0] == "treant.serve.step"]
     assert len(steps) == 1
     names = {s[0] for s in spans if _contains(steps[0], s)}

@@ -1,31 +1,42 @@
-"""From a profiler trace to device busy and idle time, kernel time and bytes.
+"""From a profiler trace to device busy and idle time, by operation and by host span.
 
 Reads the ``.xplane.pb`` that ``jax.profiler`` writes, with nothing but
 ``jax.profiler.ProfileData``.  Device operations are the events of the
 ``XLA Ops`` line of each ``/device:TPU:<n>`` plane; host spans are the
-events of the host plane whose names the benchmark gave them
-(``bench.*``, ``serve.*``).
+events of the host plane named by the benchmark (``bench.*``, ``serve.*``)
+or by the program (``treant.*``), each without a TraceMe ``#k=v#`` suffix.
 
-- busy: the union of the device operations' intervals, averaged over the
-  devices that ran any; the window is the profile's own start to stop
+- window: each device's slice from the start of its first operation to the
+  end of its last.  The idle time before and after it, the profiler's start
+  and stop latency, is reported apart as the edges.  ``window_s``,
+  ``busy_s`` and ``edges`` are averaged over the devices that ran any
+  operation; ``profile_s`` is the profile's own start to stop
+- busy: the union of the device operations' intervals in the window
 - ops: device seconds of each operation's own time (nested operations
   taken out), by its HLO instruction name without the instance number
-- calls: every call of a kernel whose name starts with one of the given
-  prefixes, with its device seconds and the bytes of its operands and
-  result, read from the HLO text that names the operation on a TPU
-  (``None`` where the trace carries no shapes)
-- gaps: device idle seconds by the host span open at the middle of each gap
+- gaps: device idle seconds inside the window by the innermost host span
+  (the one opened last) open at the middle of each gap, else ``none``;
+  they sum to ``window_s - busy_s``
+
+``reduction_bytes`` gives the bytes a segment reduction must move, from the
+shapes of the operation that reduces its rows.
 """
 
 from __future__ import annotations
 
 import glob
+import math
 import os
 import re
 
 from jax.profiler import ProfileData
 
-HOST_SPANS = ("bench.", "serve.")
+HOST_SPANS = ("bench.", "serve.", "treant.")
+# JAX primitives that reduce a segment reduction's rows: the Pallas kernel
+# and XLA's scatter, which ``jax.ops.segment_*`` lower to
+REDUCING = ("pallas_call", "scatter")
+CODE_BYTES = 4  # a row's segment code is an int32 in either implementation
+_OPCODE = re.compile(r"[A-Za-z][\w.-]*\(")
 _SHAPE = re.compile(r"\b(pred|s8|u8|s16|u16|s32|u32|s64|u64|f16|bf16|f32|f64)\[([0-9,]*)\]")
 _BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
           "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
@@ -51,7 +62,7 @@ def hlo_call(text: str) -> str | None:
         if bare == rest:
             break
         rest = bare
-    m = re.search(r"[A-Za-z][\w.-]*\(", rest)
+    m = _OPCODE.search(rest)
     if m is None:
         return None
     depth = 0
@@ -62,19 +73,48 @@ def hlo_call(text: str) -> str | None:
     return None
 
 
+def _arrays(text: str) -> list[tuple[str, list[int]]]:
+    return [(dtype, [int(d) for d in dims.split(",") if d]) for dtype, dims in _SHAPE.findall(text)]
+
+
 def hlo_bytes(text: str) -> int | None:
     """Bytes of an HLO instruction's result and operands, each once."""
     call = hlo_call(text)
     if call is None:
         return None
-    total, seen = 0, False
-    for dtype, dims in _SHAPE.findall(call):
-        n = 1
-        for d in filter(None, dims.split(",")):
-            n *= int(d)
-        total += n * _BYTES[dtype]
-        seen = True
-    return total if seen else None
+    arrays = _arrays(call)
+    return sum(math.prod(dims) * _BYTES[dtype] for dtype, dims in arrays) if arrays else None
+
+
+def reduction_bytes(text: str, path: str | None) -> int | None:
+    """The bytes a segment reduction must move: its row codes and value rows
+    read once, its result written once.  ``text`` is the HLO text of one
+    device operation, ``path`` its JAX op-name path (the ``tf_op`` stat).
+
+    Only the operation that reduces the rows counts: its primitive is a
+    Pallas call or a scatter, and its operands' longest dimension,
+    the rows N, is longer than every dimension of its result.  The result
+    holds one leading dimension per ``vmap`` in the path (the members of a
+    batch), then the segments G, then each segment's value columns, so a row
+    carries (result elements ÷ G) values.  The count takes nothing else from
+    the operands, so a Pallas kernel and an XLA reduction of the same rows,
+    segments and columns count the same bytes, whatever the XLA reduction
+    fuses in.  None for any other operation.
+    """
+    primitive = (path or "").rsplit(":", 1)[0].rsplit("/", 1)[-1]
+    call = hlo_call(text) if primitive.startswith(REDUCING) else None
+    if call is None:
+        return None
+    opcode = _OPCODE.search(call)
+    result, operands = _arrays(call[:opcode.start()]), _arrays(call[opcode.end():])
+    rows = max((d for _, dims in operands for d in dims), default=0)
+    if not result or any(d >= rows for _, dims in result for d in dims):
+        return None
+    dtype, dims = result[0]
+    members = path.count("vmap(")
+    segments = dims[members] if len(dims) > members else 1
+    cells = sum(math.prod(dims) for _, dims in result)
+    return rows * CODE_BYTES + (cells * rows // segments + cells) * _BYTES[dtype]
 
 
 def op_label(name: str) -> str:
@@ -82,6 +122,49 @@ def op_label(name: str) -> str:
     ``%segment_aggregate_sum.1 = f32[...] ...`` -> ``segment_aggregate_sum``."""
     head = name.split(" = ", 1)[0].strip().lstrip("%")
     return re.sub(r"\.\d+$", "", head)
+
+
+def span_name(name: str) -> str:
+    """A host span's name without a TraceMe ``#k=v,...#`` suffix."""
+    return name.split("#", 1)[0]
+
+
+def host_spans(data: ProfileData) -> list[tuple[str, int, int]]:
+    """``(name, start ns, end ns)`` of every host span of the benchmark's
+    and the program's, names without a TraceMe suffix."""
+    return [(span_name(ev.name), ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(HOST_SPANS)]
+
+
+def device_events(data: ProfileData) -> dict[str, list]:
+    """The events of the ``XLA Ops`` line of each TPU plane, by plane name."""
+    out = {}
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU:"):
+            lines = [ln for ln in plane.lines if ln.name == "XLA Ops"]
+            if lines:
+                out[plane.name] = list(lines[0].events)
+    return out
+
+
+def own_times(events: list) -> list[tuple[int, float]]:
+    """``(index, own ns)`` of each event in start order: its duration less
+    that of the events nested in it (a while loop holds its body's
+    operations)."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i].start_ns, -events[i].duration_ns))
+    own = [float(ev.duration_ns) for ev in events]
+    stack: list[tuple[float, int]] = []
+    for i in order:
+        s = events[i].start_ns
+        while stack and stack[-1][0] <= s:
+            stack.pop()
+        if stack:
+            own[stack[-1][1]] -= events[i].duration_ns
+        stack.append((s + events[i].duration_ns, i))
+    return [(i, max(own[i], 0.0)) for i in order]
 
 
 def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
@@ -94,81 +177,55 @@ def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     return [(s, e) for s, e in out]
 
 
-def reduce(path: str, kernels: tuple[str, ...] = ()) -> dict:
-    """Reduce one trace file; ``kernels`` are name prefixes to attribute."""
-    kernels = tuple(sorted(kernels, key=len, reverse=True))  # longest prefix wins
+def gap_label(host: list[tuple[str, int, int]], a: float, b: float) -> str:
+    """The innermost host span open at the middle of the gap ``[a, b)``."""
+    mid = (a + b) / 2
+    open_ = [(s, name) for name, s, e in host if s <= mid < e]
+    return max(open_)[1] if open_ else "none"
+
+
+def reduce(path: str) -> dict:
+    """Reduce one trace file (module docstring)."""
     data = ProfileData.from_file(path)
-    devices, host = [], []
-    for plane in data.planes:
-        if plane.name.startswith("/device:TPU:"):
-            ops = [ln for ln in plane.lines if ln.name == "XLA Ops"]
-            if ops:
-                devices.append(list(ops[0].events))
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host.extend((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
-                            for ev in line.events if ev.name.startswith(HOST_SPANS))
+    devices = list(device_events(data).values())
+    host = host_spans(data)
     # event times count from the start of the profile; the task plane
     # gives the profile's start and stop
-    env = {}
-    for plane in data.planes:
-        if plane.name == "Task Environment":
-            env = dict(plane.stats)
+    env = next((dict(p.stats) for p in data.planes if p.name == "Task Environment"), {})
     if "profile_start_time" in env and "profile_stop_time" in env:
         w0, w1 = 0.0, float(env["profile_stop_time"] - env["profile_start_time"])
-    elif host:
-        w0, w1 = min(s for _, s, _ in host), max(e for _, _, e in host)
     else:
-        w0 = w1 = 0.0
+        ends = [(ev.start_ns, ev.start_ns + ev.duration_ns) for evs in devices for ev in evs]
+        ends += [(s, e) for _, s, e in host]
+        w0, w1 = (min(s for s, _ in ends), max(e for _, e in ends)) if ends else (0.0, 0.0)
     ops: dict[str, float] = {}
-    calls: list[dict] = []
-    busy_total, gaps = 0.0, {}
-    n_dev = 0
+    gaps: dict[str, float] = {}
+    window = busy_total = start = stop = 0.0
+    ran = []
     for events in devices:
-        # an operation's own time: its duration less that of the operations
-        # nested in it (a while loop holds its body's operations)
-        evs = sorted(events, key=lambda ev: (ev.start_ns, -ev.duration_ns))
-        own = [ev.duration_ns for ev in evs]
-        stack: list[tuple[float, int]] = []
-        spans = []
-        for i, ev in enumerate(evs):
-            s, e = ev.start_ns, ev.start_ns + ev.duration_ns
-            spans.append((s, e))
-            while stack and stack[-1][0] <= s:
-                stack.pop()
-            if stack:
-                own[stack[-1][1]] -= ev.duration_ns
-            stack.append((e, i))
-            label = op_label(ev.name)
-            kernel = next((k for k in kernels if label.startswith(k)), None)
-            if kernel is not None:
-                calls.append({"kernel": kernel, "name": label,
-                              "seconds": ev.duration_ns / 1e9,
-                              "bytes": hlo_bytes(ev.name)})
-        for ev, t in zip(evs, own):
-            label = op_label(ev.name)
-            ops[label] = ops.get(label, 0.0) + max(t, 0.0) / 1e9
-        if not spans:
-            continue
-        n_dev += 1
-        if w1 <= w0:
-            w0, w1 = min(s for s, _ in spans), max(e for _, e in spans)
-        busy = _union([(max(s, w0), min(e, w1)) for s, e in spans if e > w0 and s < w1])
-        busy_total += sum(e - s for s, e in busy) / 1e9
-        edges = [w0] + [x for iv in busy for x in iv] + [w1]
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b > a:
-                mid = (a + b) / 2
-                open_ = [(s, name) for name, s, e in host if s <= mid < e]
-                label = max(open_)[1] if open_ else "none"
-                gaps[label] = gaps.get(label, 0.0) + (b - a) / 1e9 / len(devices)
-    window_s = (w1 - w0) / 1e9
+        for i, t in own_times(events):
+            label = op_label(events[i].name)
+            ops[label] = ops.get(label, 0.0) + t / 1e9
+        busy = _union([(max(ev.start_ns, w0), min(ev.start_ns + ev.duration_ns, w1))
+                       for ev in events if ev.start_ns + ev.duration_ns > w0 and ev.start_ns < w1])
+        if busy:
+            ran.append(busy)
+    for busy in ran:
+        lo, hi = busy[0][0], busy[-1][1]
+        window += (hi - lo) / 1e9 / len(ran)
+        busy_total += sum(e - s for s, e in busy) / 1e9 / len(ran)
+        start += (lo - w0) / 1e9 / len(ran)
+        stop += (w1 - hi) / 1e9 / len(ran)
+        for (_, a), (b, _) in zip(busy, busy[1:]):
+            label = gap_label(host, a, b)
+            gaps[label] = gaps.get(label, 0.0) + (b - a) / 1e9 / len(ran)
     return {
-        "window_s": window_s,
-        "busy_s": busy_total / n_dev if n_dev else 0.0,
-        "devices": n_dev,
+        "window_s": window,
+        "busy_s": busy_total,
+        "profile_s": (w1 - w0) / 1e9,
+        "edges": {"start": start, "stop": stop},
+        "devices": len(ran),
         "ops": ops,
-        "calls": calls,
         "gaps": gaps,
     }
 
