@@ -149,6 +149,17 @@ class MessageStore:
         self.widen_scans = 0
         self.widen_scan_steps = 0
         self.nbytes = 0
+        # think-time accounting: entries the think drain wrote (puts while
+        # ``thinking``), and of those the ones a later execution read and the
+        # ones evicted or gone stale unread; ``_think_unread`` holds the
+        # written entries nothing has read yet, ``_think_outputs`` collects
+        # what the open think unit dispatched (``think_unit``)
+        self.thinking = 0
+        self.think_puts = 0
+        self.think_read = 0
+        self.think_dropped = 0
+        self._think_unread: set[str] = set()
+        self._think_outputs: list[Factor] | None = None
 
     @staticmethod
     def full_sig(base_sig: str, gamma: tuple[str, ...]) -> str:
@@ -175,6 +186,48 @@ class MessageStore:
         if self._inflight_depth > 0:
             self._inflight.add(sig)
 
+    @contextlib.contextmanager
+    def think(self):
+        """Count every new entry put inside the block as think-time work."""
+        self.thinking += 1
+        try:
+            yield
+        finally:
+            self.thinking -= 1
+
+    @contextlib.contextmanager
+    def think_unit(self):
+        """A think-time unit: like :meth:`think`, and yields the list of
+        every factor put inside the block (what the unit dispatched)."""
+        outputs: list[Factor] = []
+        self._think_outputs = outputs
+        try:
+            with self.think():
+                yield outputs
+        finally:
+            self._think_outputs = None
+
+    def _note_read(self, sig: str) -> None:
+        # a read outside the think drain retires a think-written entry as read
+        if self._think_unread and not self.thinking and sig in self._think_unread:
+            self._think_unread.discard(sig)
+            self.think_read += 1
+
+    def retire_think(self, live: Mapping[str, Sequence[tuple[str, ...]]]) -> int:
+        """Count as dropped every unread think-written entry that no live
+        query probes any more.  ``live`` maps base signatures to the γ
+        carries live queries look up under them; an entry also stays live
+        when a narrower live γ could be served from it by Σ-widening."""
+        stale = []
+        for sig in self._think_unread:
+            base, gamma = self._sig_index.get(sig, (None, ()))
+            carries = live.get(base, ())
+            if not any(set(g) <= set(gamma) for g in carries):
+                stale.append(sig)
+        self._think_unread.difference_update(stale)
+        self.think_dropped += len(stale)
+        return len(stale)
+
     def get(self, base_sig: str, gamma: tuple[str, ...]) -> Factor | None:
         sig = self.full_sig(base_sig, gamma)
         f = self._data.get(sig)
@@ -182,6 +235,7 @@ class MessageStore:
             self._data.move_to_end(sig)
             self.hits += 1
             self._note_cross_hit(sig)
+            self._note_read(sig)
             self._touch(sig)
             return f
         # Σ compensation: narrow a cached wider-γ message by marginalization.
@@ -199,6 +253,7 @@ class MessageStore:
                     wide = self._data[sig2]
                     narrowed = wide.marginalize(set(g2) - gset)
                     self._note_cross_hit(sig2)
+                    self._note_read(sig2)
                     self._touch(sig2)
                     self.put(base_sig, gamma, narrowed, cost=self._cost.get(sig2))
                     self.widen_hits += 1
@@ -236,6 +291,12 @@ class MessageStore:
         self._touch(sig)
         if self.tag is not None:
             self._producer.setdefault(sig, self.tag)
+        if self.thinking:
+            if sig not in self._data:
+                self.think_puts += 1
+                self._think_unread.add(sig)
+            if self._think_outputs is not None:
+                self._think_outputs.append(f)
         self._data[sig] = f
         self._data.move_to_end(sig)
         per_base = self._widen.setdefault(base_sig, {})
@@ -361,6 +422,9 @@ class MessageStore:
         self._cost.pop(sig, None)
         self._users.pop(sig, None)
         self._drop_widen(sig)
+        if sig in self._think_unread:
+            self._think_unread.discard(sig)
+            self.think_dropped += 1
         return True
 
     def drop_producer(self, prefix: str) -> int:
@@ -472,6 +536,7 @@ class MessageStore:
         self._sig_index = {
             s: (b, g) for b, d in self._widen.items() for g, s in d.items()
         }
+        self._think_unread &= self._data.keys()
 
 
 # ---------------------------------------------------------------------------
@@ -527,6 +592,29 @@ class DeltaStats:
     edges_maintained: int = 0    # cached messages updated as old ⊕ Δ
     edges_skipped: int = 0       # outward edges with nothing cached to maintain
     fallback: bool = False       # ring cannot absorb the delta (e.g. MIN delete)
+
+
+@dataclasses.dataclass
+class UnitBudget:
+    """Fact rows one think-time unit may scan (``rows``; None: no limit).
+
+    The first dispatch of a unit always fits, so every unit makes progress;
+    ``full`` is set once a dispatch did not fit and the unit has to end."""
+
+    rows: float | None
+    spent: float = 0.0
+    messages: int = 0
+    full: bool = False
+
+    def take(self, rows: float, messages: int) -> bool:
+        if self.full or (
+            self.rows is not None and self.spent > 0 and self.spent + rows > self.rows
+        ):
+            self.full = True
+            return False
+        self.spent += rows
+        self.messages += messages
+        return True
 
 
 @dataclasses.dataclass
@@ -640,6 +728,17 @@ class CJTEngine:
         out = {b: tuple(sorted(ps, key=lambda p: p.digest)) for b, ps in placed.items()}
         self._placement_memo.put(key, out)
         return out
+
+    def bag_of_attr(self, q: Query, attr: str) -> str | None:
+        """The bag a σ on ``attr`` is placed on for ``q`` (the placement
+        rule of :meth:`place_predicates`, also when ``q`` has no σ on it)."""
+        for bag, preds in self.place_predicates(q).items():
+            if any(p.attr == attr for p in preds):
+                return bag
+        cands = self.jt.bags_with_attr(attr)
+        if not cands:
+            return None
+        return min(cands, key=lambda b: (self._bag_rows(q, b), b))
 
     def _bag_rows(self, q: Query, bag: str) -> int:
         rels = [r for r in self.jt.relations_of(bag) if r not in q.removed]
@@ -1308,6 +1407,7 @@ class CJTEngine:
         plans: Sequence[CalibrationPlan],
         stats_list: Sequence[ExecStats] | None = None,
         tags: Sequence[str | None] | None = None,
+        budget: UnitBudget | None = None,
     ) -> int:
         """Advance every unfinished plan by one level, batching across plans.
 
@@ -1324,15 +1424,26 @@ class CJTEngine:
         so a whole calibration pass costs ≤ #levels dispatches.  Returns the
         number of edges advanced; a partially-stepped level (``plan.offset``)
         is finished first.
+
+        ``budget`` (the think-time drain's unit) bounds the fact rows the
+        call scans: each batch group is dispatched in the chunks a batched
+        absorption uses (``_absorb_chunks``), charged its rows, and the call
+        stops at the first dense message or chunk that does not fit
+        (``budget.full``).  Each plan then moves past the edges of its level
+        whose messages are in the store, and to its next level once all
+        are.  A budgeted level is not fused: the budget stops between
+        chunks, and each chunk's plan is reused by every unit that meets
+        the same pair, where a fused level's plan is keyed by all its groups.
         """
         with span("treant.engine.calibrate_level", plans=len(plans)), self.store.inflight():
-            return self._run_level_inflight(plans, stats_list, tags)
+            return self._run_level_inflight(plans, stats_list, tags, budget)
 
     def _run_level_inflight(
         self,
         plans: Sequence[CalibrationPlan],
         stats_list: Sequence[ExecStats] | None = None,
         tags: Sequence[str | None] | None = None,
+        budget: UnitBudget | None = None,
     ) -> int:
         live = [i for i, p in enumerate(plans) if not p.done]
         if not live:
@@ -1351,9 +1462,10 @@ class CJTEngine:
                     # pin-before-materialize, as in calibrate_iter
                     self.store.pin(base, gamma)
                 todo.append((i, u, v, base, gamma))
-            p.pos += 1
-            p.offset = 0
-            n += len(level)
+            if budget is None:
+                p.pos += 1
+                p.offset = 0
+                n += len(level)
         deferred: list[tuple[int, str, str, str, tuple[str, ...], AbsorbItem]] = []
         pending_sigs: set[str] = set()
         for i, u, v, base, gamma in todo:
@@ -1369,8 +1481,15 @@ class CJTEngine:
                 # a sibling plan materializes this exact message below
                 st.messages_reused += 1
                 continue
-            item = self._message_item(p.query, u, v, p.placement, st, tag)
+            # a budgeted level on an engine without level batching computes
+            # message by message, as that engine's unbudgeted drain does
+            item = (
+                self._message_item(p.query, u, v, p.placement, st, tag)
+                if budget is None or self._batch_enabled() else None
+            )
             if item is None:
+                if budget is not None and not budget.take(self._bag_rows(p.query, u), 1):
+                    break
                 # dense/densified fallback goes through message(), which
                 # re-probes the sig our level probe above already counted —
                 # compensate so miss accounting matches the per-edge loop
@@ -1386,6 +1505,8 @@ class CJTEngine:
         for rec in deferred:
             groups.setdefault(absorb_batch_key(self.ring, rec[5]), []).append(rec)
         group_list = list(groups.values())
+        if budget is not None:
+            group_list = self._budget_chunks(group_list, budget)
 
         def _store_group(members, fs):
             for (i, u, v, base, gamma, item), f in zip(members, fs):
@@ -1399,7 +1520,7 @@ class CJTEngine:
                 st.messages_computed += 1
                 st.recomputed_edges.append((u, v))
 
-        if group_list and self.fuse_level_kernel:
+        if group_list and self.fuse_level_kernel and budget is None:
             # level fusion: ALL groups ride one jitted run_level call — the
             # kernel-eligible ones share a single multi-segment Pallas
             # launch — so the whole level costs ONE dispatch
@@ -1427,6 +1548,37 @@ class CJTEngine:
                 )
             self._count_dispatches(sts[0], 1)
             _store_group(members, fs)
+        if budget is not None:
+            n = sum(self._past_stored(plans[i]) for i in live)
+        return n
+
+    def _budget_chunks(self, group_list: list[list], budget: UnitBudget) -> list[list]:
+        """The chunks of each batch group (members paired in a stable order,
+        so units that meet the same messages reuse one plan) that fit
+        ``budget``, in order, up to the first that does not."""
+        out = []
+        for members in group_list:
+            members = sorted(members, key=lambda rec: (rec[4], rec[3]))
+            for chunk in self._absorb_chunks([(k, rec[5]) for k, rec in enumerate(members)]):
+                if not budget.take(sum(item.rel.num_rows for _, item in chunk), len(chunk)):
+                    return out
+                out.append([members[k] for k, _ in chunk])
+        return out
+
+    def _past_stored(self, p: CalibrationPlan) -> int:
+        """Move ``p`` past the edges of its level whose messages are stored,
+        to its next level once all are; returns the edges passed."""
+        level = p.levels[p.pos]
+        start = p.offset
+        while p.offset < len(level) and self.store.contains(
+            self.edge_sig(p.query, *level[p.offset], p.placement),
+            self.gamma_carry(p.query, *level[p.offset]),
+        ):
+            p.offset += 1
+        n = p.offset - start
+        if p.offset == len(level):
+            p.pos += 1
+            p.offset = 0
         return n
 
     def calibrate_levels_iter(

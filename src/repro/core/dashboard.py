@@ -63,6 +63,7 @@ by digest, so the chain order cannot change the digest.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import TYPE_CHECKING, Mapping
@@ -71,7 +72,7 @@ import jax
 
 from repro.relational.relation import Predicate, mask_in, mask_range
 from repro.trace import span
-from .calibration import CalibrationPlan, CJTEngine, ExecStats, factor_nbytes
+from .calibration import CalibrationPlan, CJTEngine, ExecStats, UnitBudget, factor_nbytes
 from .plans import slice_bin_cube, slice_bin_cubes
 from .predictive import (
     BrushTrajectory,
@@ -341,6 +342,19 @@ class _CalTask:
     # lowest-priority tier: compaction-triggered recalibrations run only
     # when no interactive think-time work is pending
     deprioritized: bool = False
+    # root of the calibration pass (None: ``choose_root``): the upward pass,
+    # every message directed toward it, runs first
+    root: str | None = None
+
+
+@dataclasses.dataclass
+class _Unit:
+    """The think-time unit on the device: what it put, the fact rows it
+    scans, and when its dispatch began."""
+
+    outputs: list
+    rows: float
+    t0: float
 
 
 class ThinkTimeScheduler:
@@ -355,6 +369,12 @@ class ThinkTimeScheduler:
     keeps its parked position and its partially materialized messages.
     Exhausting a ``run`` budget parks the current task without losing
     position (§4.2.1 preemptibility).
+
+    A server drains in *units* (:meth:`run_unit`): each dispatches the next
+    levels of every pending pass, vmapped across the tasks of each engine,
+    up to a row budget, and at most one unit is on the device at a time —
+    :meth:`wait_unit` retires it, timing it from its dispatch to its last
+    output ready.
     """
 
     def __init__(self):
@@ -368,6 +388,12 @@ class ThinkTimeScheduler:
         self.speculative_messages = 0  # messages those queries materialized
         self.policy_decisions = 0     # work items a ThinkTimePolicy attempted
         self.cube_builds = 0          # bin cubes materialized during idle
+        self.units = 0                # think-time units dispatched
+        self.unit_rows = 0.0          # fact rows those units scanned
+        self.unit_s = 0.0             # their dispatch → outputs-ready seconds
+        self.unit_s_max = 0.0         # the longest single unit
+        self._unit: _Unit | None = None
+        self._unit_rate: float | None = None  # rows/s of the last unit retired
         self._session_preemptions: dict[str, int] = {}
 
     def schedule(
@@ -377,6 +403,7 @@ class ThinkTimeScheduler:
         query: Query,
         engine: CJTEngine,
         deprioritized: bool = False,
+        root: str | None = None,
     ) -> None:
         key = (session, viz)
         self._seq += 1
@@ -392,7 +419,7 @@ class ThinkTimeScheduler:
             )
         self._tasks[key] = _CalTask(
             session, viz, query.digest, query, engine, priority=self._seq,
-            deprioritized=deprioritized,
+            deprioritized=deprioritized, root=root,
         )
 
     def pending(self, session: str | None = None) -> int:
@@ -459,6 +486,16 @@ class ThinkTimeScheduler:
         seconds budget needs per-edge preemption — and both modes
         park/resume the same per-task position.
         """
+        with self._store_think():
+            return self._run(budget_messages, budget_seconds, session, viz)
+
+    def _store_think(self):
+        """The shared store's think-time accounting (every engine of one
+        Treant shares one store), or a no-op with nothing scheduled."""
+        task = next(iter(self._tasks.values()), None)
+        return task.engine.store.think() if task is not None else contextlib.nullcontext()
+
+    def _run(self, budget_messages, budget_seconds, session, viz) -> int:
         done = 0
         t0 = time.perf_counter()
         while True:
@@ -498,7 +535,7 @@ class ThinkTimeScheduler:
             )
             for t in group:
                 if t.plan is None:
-                    t.plan = engine.calibration_plan(t.query)
+                    t.plan = engine.calibration_plan(t.query, root=t.root)
             before = {id(t): t.plan.edges_left() for t in group}
             if use_levels:
                 # tags attribute materializations for cross-viz sharing
@@ -530,6 +567,115 @@ class ThinkTimeScheduler:
                 and time.perf_counter() - t0 >= budget_seconds
             ):
                 return done
+
+    # -- think-time units (the server's drain) ---------------------------------
+    @property
+    def unit_pending(self) -> bool:
+        """A unit is on the device and not yet retired."""
+        return self._unit is not None
+
+    def unit_ready(self) -> bool:
+        """Every output of the outstanding unit is ready (no unit: True)."""
+        if self._unit is None:
+            return True
+        return all(
+            leaf.is_ready() for f in self._unit.outputs
+            for leaf in jax.tree_util.tree_leaves(f.field)
+        )
+
+    def wait_unit(self) -> float:
+        """Block until the outstanding unit's outputs are ready and retire
+        it; returns the seconds waited.  The unit's time, from its dispatch
+        to this wait's end, sets the row rate the next unit is sized by."""
+        unit, self._unit = self._unit, None
+        if unit is None:
+            return 0.0
+        t = time.perf_counter()
+        jax.block_until_ready([f.field for f in unit.outputs])
+        end = time.perf_counter()
+        took = end - unit.t0
+        self.unit_s += took
+        self.unit_s_max = max(self.unit_s_max, took)
+        if unit.rows > 0 and took > 0:
+            self._unit_rate = unit.rows / took
+        return end - t
+
+    def unit_rows_for(self, seconds: float, rows: float) -> float:
+        """Rows a unit may scan to take about ``seconds``: at the rate the
+        last unit ran, else ``rows`` (the caller's own rows in that time)."""
+        return rows if self._unit_rate is None else self._unit_rate * seconds
+
+    def finishes_within(self, session: str, seconds: float) -> bool:
+        """Would ``session``'s pending passes finish within ``seconds`` at
+        the row rate the last unit ran (unknown rate: yes)?"""
+        if self._unit_rate is None:
+            return True
+        rows = sum(self._remaining_cost(t) for t in self._tasks.values()
+                   if t.session == session)
+        return rows <= self._unit_rate * seconds
+
+    def run_unit(self, rows: float | None = None,
+                 held: frozenset[str] = frozenset()) -> int:
+        """Dispatch one think-time unit; returns the edges it advanced.
+
+        Completed tasks are popped first.  The pending passes then advance
+        level by level through ``CJTEngine.run_calibration_level`` under
+        one :class:`UnitBudget`, in rounds of one level per engine (engines
+        by their best task, as ``run`` picks), so every engine's upward pass — the messages toward a task's
+        ``root`` — precedes any downward level, until the unit has scanned
+        ``rows`` fact rows (at least one dispatch; None: one round).  The
+        passes of ``held`` sessions stop after their upward half.
+        Compaction tasks run only when no interactive task is pending.  The
+        caller retires the previous unit (:meth:`wait_unit`) first.
+        """
+        if self._unit is not None:
+            raise RuntimeError("a think-time unit is still outstanding")
+        for key in [k for k, t in self._tasks.items()
+                    if t.plan is not None and t.plan.done]:
+            del self._tasks[key]
+            self.completed += 1
+        tasks = list(self._tasks.values())
+        if any(not t.deprioritized for t in tasks):
+            tasks = [t for t in tasks if not t.deprioritized]
+        if not tasks:
+            return 0
+        groups: dict[int, list[_CalTask]] = {}
+        for t in sorted(tasks, key=lambda t: (self._remaining_cost(t), -t.priority)):
+            if t.plan is None:
+                t.plan = t.engine.calibration_plan(t.query, root=t.root)
+            groups.setdefault(id(t.engine), []).append(t)
+        budget = UnitBudget(rows)
+        done = 0
+        t0 = time.perf_counter()
+        store = tasks[0].engine.store
+        with span("treant.scheduler.unit") as mark, store.think_unit() as outputs:
+            while not budget.full:
+                advanced = 0
+                for group in groups.values():
+                    live = [t for t in group if not t.plan.done and (
+                        t.session not in held or t.plan.pos < len(t.plan.levels) // 2)]
+                    if not live:
+                        continue
+                    before = [t.plan.edges_left() for t in live]
+                    n = live[0].engine.run_calibration_level(
+                        [t.plan for t in live],
+                        tags=[f"{t.session}:{t.viz}" for t in live], budget=budget,
+                    )
+                    for t, b in zip(live, before):
+                        t.done += b - t.plan.edges_left()
+                    advanced += n
+                    if budget.full:
+                        break
+                done += advanced
+                if not advanced or rows is None:
+                    break
+            mark.set_metadata(messages=budget.messages, rows=int(budget.spent))
+        self.messages += done
+        if outputs:
+            self._unit = _Unit(outputs, budget.spent, t0)
+            self.units += 1
+            self.unit_rows += budget.spent
+        return done
 
     def speculate(
         self, session: str, items: list[tuple[str, Query, CJTEngine]]
@@ -573,6 +719,10 @@ class ThinkTimeScheduler:
             "speculative_messages": self.speculative_messages,
             "policy_decisions": self.policy_decisions,
             "cube_builds": self.cube_builds,
+            "units": self.units,
+            "unit_rows": self.unit_rows,
+            "unit_s": self.unit_s,
+            "unit_s_max": self.unit_s_max,
         }
 
 
@@ -760,7 +910,9 @@ class Session:
         """
         if not self._record(event):
             return ApplyResult(event, (), {}, dict(self._current), 0.0)
-        return self._fan_out(event)
+        result = self._fan_out(event)
+        self._treant._retire_think()
+        return result
 
     def _record(self, event) -> bool:
         """Validate + apply one event to the declarative state WITHOUT

@@ -327,6 +327,24 @@ class Treant:
             for q in sess._pinned_queries.values()
         ]
 
+    def _retire_think(self) -> None:
+        """Count the think-time entries no session's current query (nor a
+        pending calibration) reads any more as dropped: the drain's work
+        that went stale before an event used it."""
+        if not self.store._think_unread:
+            return
+        live: dict[str, list[tuple[str, ...]]] = {}
+        queries = {q.digest: q for sess in self._sessions.values()
+                   for q in sess._current.values()}
+        queries.update((t.digest, t.query) for t in self.scheduler._tasks.values())
+        for q in queries.values():
+            eng = self.engine_for(q.ring_name, q.measure)
+            placement = eng.place_predicates(q)
+            for u, v in self.jt.directed_edges():
+                live.setdefault(eng.edge_sig(q, u, v, placement), []).append(
+                    eng.gamma_carry(q, u, v))
+        self.store.retire_think(live)
+
     def _sees(self, q: Query, relation: str) -> bool:
         """Can ``relation``'s data reach this query's answer?"""
         return relation not in q.removed and relation in self.jt.mapping
@@ -606,6 +624,13 @@ class Treant:
             "widen_scans": self.store.widen_scans,
             "widen_scan_steps": self.store.widen_scan_steps,
             "cross_viz_hits": self.store.cross_tag_hits,
+            # entries the think-time drain wrote; of those, the ones an
+            # execution read, and the ones evicted or stale before any read
+            "store": {
+                "think_puts": self.store.think_puts,
+                "think_read": self.store.think_read,
+                "think_dropped": self.store.think_dropped,
+            },
             "scheduler": self.scheduler.stats(),
             "sessions": len(self._sessions),
             "watermark": self.catalog.watermark,

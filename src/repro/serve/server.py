@@ -35,7 +35,9 @@ engine into a serving tier:
 
 - **Server-driven think-time.**  ``idle`` uses empty-queue capacity to run
   background ``flush()`` ticks (streaming ingest moves off the caller
-  thread), drain the shared :class:`ThinkTimeScheduler`, and run the
+  thread), advance the shared :class:`ThinkTimeScheduler` by one bounded
+  *unit* (at most one is ever on the device; ``step`` waits for it before
+  dispatching, so an event waits behind one unit at most), and run the
   configured :class:`~repro.core.predictive.ThinkTimePolicy`'s speculative
   extras per session — σ-prefetch fan-outs and bin cubes both land in a
   *shared* pool any session may hit (a pooled γ∪{dim} cube serves every σ
@@ -78,6 +80,12 @@ from repro.core.treant import Treant
 from repro.trace import span
 
 
+# the longest ``idle`` sleeps while a think-time unit runs or a session's
+# hold keeps everything back: how late the caller can see an event that
+# falls due meanwhile
+IDLE_POLL_S = 1e-3
+
+
 class QueueFull(RuntimeError):
     """Raised by ``submit`` under ``backpressure="reject"`` when the bounded
     event queue is at capacity (the client should retry after a beat)."""
@@ -103,6 +111,7 @@ class ServeStats:
     think_time_messages: int = 0      # calibration edges advanced while idle
     errors: int = 0                   # events whose _record raised
     queue_wait_s: float = 0.0         # Σ over processed events of submit → drained
+    think_wait_s: float = 0.0         # Σ of step()'s waits for a think-time unit
 
 
 @dataclasses.dataclass
@@ -145,6 +154,16 @@ class ServerSession:
         # per-session results of the last batch this session participated in
         self.last_result: ApplyResult | None = None
         self._pinned_wm = server.treant.catalog.pin_watermark()
+        # the session's pace: when its last event was served, and the last
+        # two gaps from a served event to the session's next submit
+        self._served_at: float | None = None
+        self._gaps: deque[float] = deque(maxlen=2)
+
+    def _pace(self) -> float | None:
+        """The shorter of the session's last two gaps.  A drag repeats its
+        gap and a pause breaks it, so its last result plus the pace is when
+        a drag's next step comes."""
+        return min(self._gaps) if self._served_at is not None and self._gaps else None
 
     def submit(self, event) -> None:
         self._server.submit(self.id, event)
@@ -180,7 +199,6 @@ class TreantServer:
         max_queue: int = 256,
         backpressure: str = "drain",
         max_store_bytes: int | None = None,
-        think_budget_messages: int = 64,
         speculate: int = 0,
         pool_capacity: int = 256,
         policy: ThinkTimePolicy | None = None,
@@ -190,7 +208,6 @@ class TreantServer:
         self.treant = treant
         self.max_queue = max_queue
         self.backpressure = backpressure
-        self.think_budget_messages = think_budget_messages
         self.speculate = speculate
         if speculate:
             warn_deprecated_once(
@@ -216,6 +233,9 @@ class TreantServer:
         # budget, minus pins: recency, then recompute cost
         self._pool: dict[str, _Pooled] = {}
         self.stats_ = ServeStats()
+        # the last executing step's (seconds from dispatch to results ready,
+        # fact rows scanned): what one think-time unit may cost
+        self._served: tuple[float, float] | None = None
 
     # -- sessions -------------------------------------------------------------
     def open_session(
@@ -242,8 +262,12 @@ class TreantServer:
     # -- event queue ----------------------------------------------------------
     def submit(self, sid: str, event) -> None:
         """Enqueue one event; coalesce superseded queued work; backpressure."""
-        if sid not in self._sessions:
+        handle = self._sessions.get(sid)
+        if handle is None:
             raise KeyError(f"no server session {sid!r}")
+        now = time.perf_counter()
+        if handle._served_at is not None:
+            handle._gaps.append(now - handle._served_at)
         self.stats_.events_submitted += 1
         self._coalesce(sid, event)
         if len(self._queue) >= self.max_queue:
@@ -254,7 +278,7 @@ class TreantServer:
                 )
             self.stats_.backpressure_drains += 1
             self.step()
-        self._queue.append(_Queued(sid, event, self._seq, time.perf_counter()))
+        self._queue.append(_Queued(sid, event, self._seq, now))
         self._seq += 1
         self.stats_.queue_peak = max(self.stats_.queue_peak, len(self._queue))
 
@@ -348,8 +372,14 @@ class TreantServer:
                         participants.append((handle, q.event))
             with span("treant.serve.fan_out"):
                 self._fan_out(participants)
+            served_at = time.perf_counter()
+            for q in batch:
+                handle = self._sessions.get(q.sid)
+                if handle is not None:
+                    handle._served_at = served_at
             for handle, _ in participants:
                 handle._refresh_pin()
+            self.treant._retire_think()
         return len(batch)
 
     def _fan_out(self, participants: list[tuple[ServerSession, object]]) -> None:
@@ -426,6 +456,12 @@ class TreantServer:
         #    absorptions ride one vmapped dispatch
         executed: dict[str, tuple[object, ExecStats]] = {}
         pending = []
+        if uniques and self.treant.scheduler.unit_pending:
+            # the think-time unit on the device goes first: wait it out here,
+            # where the wait is counted, rather than behind the batch
+            with span("treant.serve.think_wait"):
+                self.stats_.think_wait_s += self.treant.scheduler.wait_unit()
+        t_served = time.perf_counter()
         for engine, items in _group_by_engine(
             (self.treant.engine_for(q.ring_name, q.measure), (handle, viz, q))
             for handle, viz, q in uniques
@@ -451,6 +487,10 @@ class TreantServer:
         if pending:
             with span("treant.serve.wait"):
                 jax.block_until_ready([f.field for f in pending])
+            self._served = (
+                time.perf_counter() - t_served,
+                float(sum(st.rows_scanned for _, st in executed.values())),
+            )
         with span("treant.serve.distribute"):
             # cross-session width: the max of (a) distinct sessions inside one
             # vmapped dispatch and (b) distinct sessions sharing one deduped
@@ -502,7 +542,12 @@ class TreantServer:
 
     def _schedule(self, handle: ServerSession, viz: str, q: Query,
                   engine: CJTEngine) -> None:
-        self.treant.scheduler.schedule(handle.id, viz, q, engine)
+        # root the pass at the bag the session brushed last: its upward
+        # pass, the messages toward that bag, is what the drag's next step
+        # reads, and it stays valid while the brush moves
+        last = handle.session._last_filter
+        root = engine.bag_of_attr(q, last.attr) if last is not None else None
+        self.treant.scheduler.schedule(handle.id, viz, q, engine, root=root)
 
     def _probe_pool_cube(self, sess: Session, q: Query, pool_dims):
         """Serve ``q`` from a pooled bin cube (possibly another session's):
@@ -529,16 +574,31 @@ class TreantServer:
         return None
 
     # -- server-driven think-time ----------------------------------------------
-    def idle(self, budget_messages: int | None = None) -> int:
+    def idle(self) -> int:
         """Spend empty-queue capacity on background work.
 
         Background flush always runs first (queued stream data makes every
-        other think-time item stale), then ONE global scheduler drain under
-        ``budget_messages`` (default: the server's configured budget), then
-        the think-time policy's speculative extras per session
-        (``self.policy``, else the Treant's default) — σ prefetch and/or bin
-        cubes, both published into the shared pool so ANY session hitting
-        the same digest (or any σ on a pooled cube's dimension) is served.
+        other think-time item stale), then the shared scheduler's drain
+        advances by one unit, then the think-time policy's speculative
+        extras run per session (``self.policy``, else the Treant's default)
+        — σ prefetch and/or bin cubes, both published into the shared pool
+        so ANY session hitting the same digest (or any σ on a pooled cube's
+        dimension) is served.
+
+        At most one think-time unit is on the device: a call that finds the
+        previous unit still running dispatches nothing and returns within
+        ``IDLE_POLL_S``, so the caller serves what fell due meanwhile, and
+        ``step`` waits the unit out where the wait is counted.  A unit is
+        sized to take about as long as the last executing step did from
+        dispatch to results (``_served``), at the row rate the previous
+        unit ran; it dispatches the pending passes' messages toward the bag
+        each session brushed last first (those stay valid through a drag).
+        The rest of a session's pass, which the drag's next step would
+        invalidate, waits until the session has been away for twice its
+        pace (``ServerSession._pace``), unless its pending passes would
+        finish, at the last unit's rate, before it is expected back; a call
+        that the hold leaves nothing to dispatch sleeps ``IDLE_POLL_S`` at
+        most.
         Returns the number of calibration edges advanced.
         """
         if self._queue:
@@ -550,12 +610,35 @@ class TreantServer:
                 self.stats_.background_flushes += 1
                 for handle in self._sessions.values():
                     handle._refresh_pin()
-            budget = (
-                budget_messages if budget_messages is not None
-                else self.think_budget_messages
-            )
+            scheduler = self.treant.scheduler
             with span("treant.scheduler.run"):
-                done = self.treant.scheduler.run(budget_messages=budget)
+                done, poll = 0, IDLE_POLL_S
+                if scheduler.unit_ready():
+                    scheduler.wait_unit()  # retire it: its time sizes the next
+                    now = time.perf_counter()
+                    holds = {}
+                    for sid, h in self._sessions.items():
+                        pace = h._pace()
+                        if pace is None:
+                            continue
+                        # expected back at ``back``; held for a pace more,
+                        # so a step that comes a little late finds no unit
+                        back = h._served_at + pace
+                        if now < back + pace and not scheduler.finishes_within(sid, back - now):
+                            holds[sid] = back + pace
+                    done = scheduler.run_unit(
+                        None if self._served is None
+                        else scheduler.unit_rows_for(*self._served),
+                        held=frozenset(holds))
+                    held_back = holds and scheduler.pending() and not scheduler.unit_pending
+                    poll = min(poll, min(holds.values()) - now) if held_back else 0.0
+                if poll > 0:
+                    # the unit still runs, or a hold keeps the rest back:
+                    # come back within a poll, so an event that falls due
+                    # meanwhile is served next (``step`` waits for the unit
+                    # and counts that wait) and the session's gaps stay its own
+                    with span("treant.scheduler.poll"):
+                        time.sleep(poll)
             self.stats_.think_time_messages += done
             policy = self.policy or self.treant.think_time_policy
             extras_budget = ThinkTimeBudget()
