@@ -225,9 +225,11 @@ class PlanStats:
     cube_slices: int = 0
     # rowwise gathers of executed sparse plans, per member, from each plan's
     # static count: tables (dimension messages, σ masks) gathered at the row
-    # codes by one-hot contraction, and by ``jnp.take``
+    # codes by one-hot contraction, and by ``jnp.take``; of the one-hot ones,
+    # those that contracted the infinity split (tables that can hold ±inf)
     onehot_gathers: int = 0
     take_gathers: int = 0
+    onehot_split_gathers: int = 0
 
     # counters that are high-water marks, not sums: cross-engine aggregation
     # (Treant.cache_stats) takes max for these and Σ for everything else
@@ -330,8 +332,9 @@ class _Plan:
     # collective payloads (one per output factor per dispatch)
     sharded: bool = False
     allreduce_bytes: int = 0
-    # rowwise gathers per member as (one-hot, take); level plans: per group
-    gathers: tuple = (0, 0)
+    # rowwise gathers per member as (one-hot, take, of the one-hot those with
+    # the infinity split); level plans: per group
+    gathers: tuple = (0, 0, 0)
     group_gathers: tuple = ()
 
 
@@ -349,7 +352,7 @@ class _SparseMeta:
     use_kernel: bool
     cost: int
     row_bytes: int                   # device bytes the body holds per row
-    gathers: tuple[int, int]         # rowwise gathers: (one-hot, take)
+    gathers: tuple[int, int, int]    # rowwise gathers: (one-hot, take, split)
 
 
 # ---------------------------------------------------------------------------
@@ -455,22 +458,46 @@ def _blocked(build: Callable, n: int, ring: sr.Semiring, row_major):
 # D = 265 and grows with D (36 ms at 2048, 76 ms at 4096, for 7 columns of 2
 # members); ``take`` takes 35-93 ms from D = 96 up, but under 1 ms for a
 # single column of at most 64 entries.
+#
+# A table of a ring whose 0̄ is infinite (tropical MIN/MAX: 0̄ = ±inf) can hold
+# ±inf, and 0·inf is NaN, so its contraction splits each column in two: the
+# finite part (infinities as 0) and a signed indicator (-1 at -inf, +1 at
+# +inf, else 0), stacked as 2k columns of one dot; the gathered indicator
+# selects the infinity back.  Every term of each contraction but one is an
+# exact 0, so the split is as exact as the finite gather.  Compiled for a
+# v5e, the taxi MAX plan (2 members, 2^23 rows) writes (2, 2, N) dot outputs
+# and needs 403 MB of temporaries where ``take`` wrote (N, 2) tiled to 128
+# lanes and needed 4.46 GB; its chip time is not measured yet.
 ONEHOT_MAX_DOMAIN = 4096
 TAKE_MAX_DOMAIN = 64
 
 
-def _onehot_rows(table: jax.Array, codes: jax.Array) -> jax.Array:
+def _onehot_rows(table: jax.Array, codes: jax.Array,
+                 infinities: bool = False) -> jax.Array:
     """A (D, *cells) table at the (N,) row codes as (*cells, N), by one-hot
     contraction.  At ``HIGHEST`` every pass is exact against a 0/1 one-hot,
-    so this equals ``jnp.take`` bit for bit wherever the table is finite."""
+    so this equals ``jnp.take`` wherever the table is finite (a -0.0 entry
+    may come out +0.0, the same number).  ``infinities``: the table may hold
+    ±inf, so contract its finite part and a signed indicator of its
+    infinities together and select the infinities back; then it equals
+    ``jnp.take`` for any table without NaN."""
     d = table.shape[0]
     hot = jax.lax.broadcasted_iota(codes.dtype, (d,) + codes.shape, 0) == codes[None]
+    cols = table.reshape((d, -1)).astype(jnp.float32)
+    if infinities:
+        inf = jnp.isinf(cols)
+        cols = jnp.concatenate(
+            [jnp.where(inf, 0.0, cols), jnp.where(inf, jnp.sign(cols), 0.0)], axis=1
+        )
     out = jax.lax.dot_general(
-        table.reshape((d, -1)).astype(jnp.float32), hot.astype(jnp.float32),
+        cols, hot.astype(jnp.float32),
         (((0,), (0,)), ((), ())),
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
+    if infinities:
+        finite, sign = jnp.split(out, 2)
+        out = jnp.where(sign == 0, finite, sign * jnp.inf)
     return out.reshape(table.shape[1:] + codes.shape)
 
 
@@ -480,13 +507,15 @@ def _take_rows(table: jax.Array, codes: jax.Array, ncells: int) -> jax.Array:
     return jnp.take(jnp.moveaxis(table, 0, ncells), codes, axis=ncells, mode="clip")
 
 
-def _use_onehot(finite: bool, d: int, cols: int) -> bool:
-    """Whether a rowwise gather of a D-entry table contracts a one-hot: only
-    where every entry is finite (0·x is NaN for x = ±inf, the tropical 0̄),
-    D is at most :data:`ONEHOT_MAX_DOMAIN`, and the gather is not a single
-    column (one member, k = 1) of at most :data:`TAKE_MAX_DOMAIN` entries,
-    which ``take`` writes densely and fast."""
-    return finite and d <= ONEHOT_MAX_DOMAIN and (cols > 1 or d > TAKE_MAX_DOMAIN)
+def _use_onehot(d: int, cols: int) -> bool:
+    """Whether a rowwise gather of a D-entry table in ``cols`` columns
+    contracts a one-hot: D is at most :data:`ONEHOT_MAX_DOMAIN`, and the
+    gather is not a single column (one member, k = 1) of at most
+    :data:`TAKE_MAX_DOMAIN` entries, which ``take`` writes densely and fast.
+    One rule for σ masks and for the messages of every scalar f32 ring; the
+    tables of a ring whose 0̄ is infinite take the infinity split
+    (:func:`_onehot_rows`)."""
+    return d <= ONEHOT_MAX_DOMAIN and (cols > 1 or d > TAKE_MAX_DOMAIN)
 
 
 def _sparse_plan_parts(
@@ -528,20 +557,23 @@ def _sparse_plan_parts(
     )
 
     # each gather's path, from the ring and the table's shape: messages of
-    # the rings whose ⊗ is × and 0̄ is 0 (f32 SUM/COUNT) may go one-hot, σ
-    # masks (0/1 values) in every ring
-    arith = ring.is_arithmetic and ring.dtype == jnp.float32 and ring.trailing == (0,)
+    # the scalar f32 rings (SUM, COUNT, MIN, MAX) and σ masks (0/1 values)
+    # in every ring may go one-hot; compound, bool and integer rings' messages
+    # keep take.  Tables of the tropical rings (0̄ = ±inf) can hold ±inf and
+    # take the infinity split, which the others' compiled plans never hold.
+    scalar = ring.dtype == jnp.float32 and ring.trailing == (0,)
+    infinities = ring.kernel_segment_op in ("min", "max")
     msg_onehot = tuple(
-        _use_onehot(
-            arith,
+        scalar and _use_onehot(
             int(np.prod([doms[a] for a in shared])),
             members * int(np.prod([doms[a] for a in extra])),
         ) if shared else None
         for _, shared, extra, _, _ in steps
     )
-    mask_onehot = tuple(_use_onehot(True, doms[a], members) for a in pred_attrs)
+    mask_onehot = tuple(_use_onehot(doms[a], members) for a in pred_attrs)
     paths = [p for p in msg_onehot + mask_onehot if p is not None]
-    gathers = (sum(paths), len(paths) - sum(paths))
+    split = sum(p for p in msg_onehot if p) if infinities else 0
+    gathers = (sum(paths), len(paths) - sum(paths), split)
 
     op = ring.kernel_segment_op
     vcols = int(np.prod(carried_dims)) if carried_dims else 1
@@ -567,7 +599,7 @@ def _sparse_plan_parts(
                 def gather(leaf):
                     lead = leaf.reshape((d,) + leaf.shape[len(shared):])
                     if onehot:
-                        return _onehot_rows(lead, idx)
+                        return _onehot_rows(lead, idx, infinities)
                     if shared:
                         return _take_rows(lead, idx, len(extra))
                     cells = lead.shape[1 : 1 + len(extra)]
@@ -629,8 +661,9 @@ def _sparse_plan_parts(
                 )
         return finalize(field)
 
-    # the gathered message, its ⊗ product and the σ-masked slab
-    row_bytes = 3 * vcols * len(ring.trailing) * np.dtype(ring.dtype).itemsize
+    # the gathered message, its ⊗ product and the σ-masked slab; a gather with
+    # the infinity split writes its dot's 2k rows before the select, one slab more
+    row_bytes = (3 + (split > 0)) * vcols * len(ring.trailing) * np.dtype(ring.dtype).itemsize
     meta = _SparseMeta(
         total=total, carried_dims=carried_dims, use_kernel=use_kernel, cost=cost,
         row_bytes=row_bytes, gathers=gathers,
@@ -1407,10 +1440,11 @@ class PlanCache:
             stats.plan_hits += int(not traced)
             stats.kernel_execs += int(entry.uses_kernel)
 
-    def _account_gathers(self, gathers: tuple[int, int]) -> None:
-        """One member's rowwise gathers: (one-hot, take)."""
+    def _account_gathers(self, gathers: tuple[int, int, int]) -> None:
+        """One member's rowwise gathers: (one-hot, take, split)."""
         self.stats.onehot_gathers += gathers[0]
         self.stats.take_gathers += gathers[1]
+        self.stats.onehot_split_gathers += gathers[2]
 
     @property
     def plan_shards(self) -> int:

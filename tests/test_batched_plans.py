@@ -414,6 +414,7 @@ from test_plans import (  # noqa: E402 — the plan-level take reference
     LANE_OUT,
     LANE_REL,
     LANE_RINGS,
+    TROPICAL_ZERO,
     assert_lane_equal,
     lane_args,
     lane_device,
@@ -458,11 +459,12 @@ def test_lane_dense_batched_plan_matches_take(ring_name, width, sigma):
     assert len(outs) == width
     for out, want in zip(outs, wants):
         assert_lane_equal(out, want)
-    # vmapped, every gather is wider than one column: σ masks go one-hot in
-    # every ring, messages only where 0̄ is 0 (the tropical 0̄ is -inf)
-    onehot, take = plan.gathers
+    # vmapped, every gather is wider than one column: all go one-hot in
+    # every ring, the tropical messages (0̄ = ±inf) with the infinity split
+    onehot, take, split = plan.gathers
     assert onehot + take == 3 + len(sigma)
-    assert take == (3 if ring_name == "tropical_max" else 0)
+    assert take == 0
+    assert split == (3 if ring_name in TROPICAL_ZERO else 0)
 
 
 @pytest.mark.parametrize("ring_name", sorted(LANE_RINGS))
@@ -491,9 +493,10 @@ def test_lane_dense_level_plan_matches_take(ring_name):
 
 def test_rowwise_gather_counters_per_member():
     """``PlanStats`` counts each executed member's gathers from its plan's
-    static count, as (one-hot, take): the same queries over the tropical
-    ring, whose messages hold 0̄ = -inf, move the message gathers of the
-    vmapped members from one-hot to take."""
+    static count, as (one-hot, take): the same queries over SUM and over the
+    tropical ring, whose messages hold 0̄ = -inf, split their gathers alike,
+    and ``onehot_split_gathers`` counts the tropical message gathers among
+    the one-hot ones, and none of SUM's."""
     cat = star_catalog(seed=31)
     jt = jt_from_catalog(cat)
     counts = {}
@@ -504,10 +507,10 @@ def test_rowwise_gather_counters_per_member():
         eng.execute_many(qs)
         st = eng.plans.stats
         assert st.batched_absorptions == 3
-        counts[ring_name] = (st.onehot_gathers, st.take_gathers)
-    (o_sum, t_sum), (o_max, t_max) = counts["sum"], counts["tropical_max"]
-    assert o_sum + t_sum == o_max + t_max
-    assert o_sum >= 3 and o_max == 0 and t_max == t_sum + o_sum
+        counts[ring_name] = (st.onehot_gathers, st.take_gathers, st.onehot_split_gathers)
+    (o_sum, t_sum, s_sum), (o_max, t_max, s_max) = counts["sum"], counts["tropical_max"]
+    assert (o_sum, t_sum) == (o_max, t_max)
+    assert o_sum >= 3 and s_sum == 0 and 3 <= s_max <= o_max
 
 
 def test_rowwise_gather_counters_in_cache_stats():
@@ -517,7 +520,7 @@ def test_rowwise_gather_counters_in_cache_stats():
     sess.apply(SetFilter("a", values=(0, 1), source="by_a"))
     plans = t.cache_stats()["plans"]
     assert plans["batched_absorptions"] > 0 and plans["onehot_gathers"] > 0
-    assert plans["take_gathers"] >= 0
+    assert plans["take_gathers"] >= 0 and plans["onehot_split_gathers"] == 0
 
 
 def test_rowwise_onehot_pct_reads_the_gather_counters():

@@ -325,7 +325,9 @@ def test_catalog_dev_codes_cached_and_lru_bounded():
 # flat two-attr code (b, c: 70 entries, over the one-column take limit) and
 # c; the plans below are built from these statics directly, so every plan
 # kind meets the same contraction.
-LANE_RINGS = {"sum": sr.SUM, "count": sr.COUNT, "tropical_max": sr.TROPICAL_MAX}
+LANE_RINGS = {"sum": sr.SUM, "count": sr.COUNT, "tropical_min": sr.TROPICAL_MIN,
+              "tropical_max": sr.TROPICAL_MAX}
+TROPICAL_ZERO = {"tropical_min": np.inf, "tropical_max": -np.inf}
 LANE_REL = ("a", "b", "c")
 LANE_DOMS = {"a": 13, "b": 7, "c": 10, "x": 6, "y": 4}
 LANE_IN = (("a", "x"), ("b", "c"), ("c", "y"))
@@ -339,7 +341,7 @@ def lane_rows(n: int, seed: int) -> dict:
 
 def lane_member(ring_name: str, codes: dict, dims: dict, sigma: tuple, seed: int):
     """One member's (vals, fields, masks) as host arrays: small integers, so
-    f32 ⊗ and ⊕ are exact in any order; tropical messages hold 0̄ (-inf)."""
+    f32 ⊗ and ⊕ are exact in any order; tropical messages hold 0̄ (±inf)."""
     rng = np.random.default_rng(seed)
     n = len(codes["a"])
     vals = (np.ones(n, np.float32) if ring_name == "count"
@@ -347,8 +349,8 @@ def lane_member(ring_name: str, codes: dict, dims: dict, sigma: tuple, seed: int
     fields = []
     for m in LANE_IN:
         f = rng.integers(0, 4, [dims[a] for a in m]).astype(np.float32)
-        if ring_name == "tropical_max":
-            f[rng.random(f.shape) < 0.2] = -np.inf
+        if ring_name in TROPICAL_ZERO:
+            f[rng.random(f.shape) < 0.2] = TROPICAL_ZERO[ring_name]
         fields.append(f)
     masks = [rng.random(LANE_DOMS[a]) < 0.6 for a in sigma]
     return vals, fields, masks
@@ -357,9 +359,9 @@ def lane_member(ring_name: str, codes: dict, dims: dict, sigma: tuple, seed: int
 def take_reference(ring_name: str, codes: dict, dims: dict, vals, fields, sigma, masks):
     """The contraction in numpy, gathering row-major with ``take`` as the
     plans did before their rowwise stage went lane-major: (x, a, y)."""
-    tropical = ring_name == "tropical_max"
+    tropical = ring_name in TROPICAL_ZERO
     mul = np.add if tropical else np.multiply
-    zero = np.float32(-np.inf if tropical else 0.0)
+    zero = np.float32(TROPICAL_ZERO.get(ring_name, 0.0))
     out = vals[:, None, None]
     out = mul(out, np.take(fields[0], codes["a"], axis=0)[:, :, None])
     flat = codes["b"] * LANE_DOMS["c"] + codes["c"]
@@ -370,7 +372,8 @@ def take_reference(ring_name: str, codes: dict, dims: dict, vals, fields, sigma,
         keep &= m[codes[a]]
     out = np.where(keep[:, None, None], out, zero)
     res = np.full((LANE_DOMS["a"], dims["x"], dims["y"]), zero, np.float32)
-    (np.maximum if tropical else np.add).at(res, codes["a"], out)
+    add = {"tropical_min": np.minimum, "tropical_max": np.maximum}.get(ring_name, np.add)
+    add.at(res, codes["a"], out)
     return res.transpose(1, 0, 2)
 
 
@@ -419,11 +422,10 @@ def test_lane_dense_scalar_plan_matches_take(ring_name, sigma, reduce_path):
     assert_lane_equal(out, take_reference(ring_name, codes, LANE_DOMS, vals, fields,
                                           sigma, masks))
     # one member: the σ masks are single columns of at most 64 entries and
-    # stay on take; the messages go one-hot where the ring's 0̄ is 0
-    if ring_name == "tropical_max":
-        assert plan.gathers == (0, 3 + len(sigma))
-    else:
-        assert plan.gathers == (3, len(sigma))
+    # stay on take; the messages go one-hot in every ring, the tropical ones
+    # (0̄ = ±inf) with the infinity split
+    split = 3 if ring_name in TROPICAL_ZERO else 0
+    assert plan.gathers == (3, len(sigma), split)
 
 
 @pytest.mark.parametrize("ring_name", sorted(LANE_RINGS))
@@ -505,4 +507,62 @@ def test_lane_dense_two_shard_plans_match_take():
         env=env, capture_output=True, text=True, timeout=600,
     )
     assert out.returncode == 0, out.stderr[-3000:]
-    assert json.loads(out.stdout.strip().splitlines()[-1])["checked"] == 3 * 2 * 3
+    checked = json.loads(out.stdout.strip().splitlines()[-1])["checked"]
+    assert checked == len(LANE_RINGS) * 2 * 3
+
+
+# ---------------------------------------------------------------------------
+# one-hot gathers of tables that hold infinities ≡ ``take``
+# ---------------------------------------------------------------------------
+
+def _gather_table(shape: tuple, infs: str, seed: int) -> np.ndarray:
+    """A (D, *cells) f32 table of wide-ranging finite values, ±0.0 and the
+    largest finite magnitudes, with -inf and/or +inf in about a fifth of
+    its entries each as ``infs`` asks."""
+    rng = np.random.default_rng(seed)
+    t = (rng.standard_normal(shape) * 10.0 ** rng.integers(-30, 30, shape)).astype(np.float32)
+    flat = t.reshape(-1)
+    flat[:4] = [-0.0, 0.0, np.finfo(np.float32).max, -np.finfo(np.float32).max]
+    for sign in {"neg": (-1,), "pos": (1,), "both": (-1, 1), "none": ()}[infs]:
+        t[rng.random(shape) < 0.2] = sign * np.inf
+    return t
+
+
+def _assert_same_bits(got: np.ndarray, want: np.ndarray):
+    """Bit for bit, but for the sign of a zero: a -0.0 entry gathered by
+    contraction sums exact zeros and comes out +0.0, the same number."""
+    assert got.shape == want.shape and got.dtype == want.dtype == np.float32
+    zero = want == 0
+    np.testing.assert_array_equal(got[zero], 0.0)
+    np.testing.assert_array_equal(got.view(np.uint32)[~zero], want.view(np.uint32)[~zero])
+
+
+@pytest.mark.parametrize("shape", [(300,), (31, 3, 2), (2, 265)],
+                         ids=["one-column", "carried-columns", "vmapped-pair"])
+@pytest.mark.parametrize("infs", ["neg", "pos", "both", "none"])
+def test_onehot_rows_matches_take(infs, shape):
+    """``_onehot_rows`` with the infinity split gives ``jnp.take``'s values
+    for tables holding -inf, +inf, both or neither, next to ±0.0 and the
+    largest finite magnitudes: one column, several carried columns, and a
+    vmapped pair sharing its row codes.  A finite table also takes the
+    plain contraction (SUM/COUNT's) to the same values.  The tropical
+    plans end to end: the ``tropical_min``/``tropical_max`` cases of the
+    lane-dense plan tests above."""
+    from repro.core import plans as plans_mod
+
+    table = _gather_table(shape, infs, seed=len(shape) + len(infs))
+    vmapped = shape == (2, 265)
+    d = shape[1] if vmapped else shape[0]
+    codes = np.random.default_rng(7).integers(0, d, 4096).astype(np.int32)
+    axis = 1 if vmapped else 0
+    want = np.moveaxis(np.take(table, codes, axis=axis), axis, -1)
+
+    def gather(infinities):
+        def one(t):
+            return plans_mod._onehot_rows(t, jnp.asarray(codes), infinities)
+        f = jax.vmap(one) if vmapped else one
+        return np.asarray(jax.jit(f)(jnp.asarray(table)))
+
+    _assert_same_bits(gather(True), want)
+    if infs == "none":
+        _assert_same_bits(gather(False), want)

@@ -193,7 +193,7 @@ def test_taxi_batched_rowwise_is_lane_dense(one_chip, as_tpu_process):
     plan = plans_mod._build_batched_sparse_plan(
         sr.SUM, rel, doms, in_canon, ("hour",), ("·0",), ROWS, members
     )
-    assert plan.gathers == (4, 0)
+    assert plan.gathers == (4, 0, 0)
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -211,6 +211,45 @@ def test_taxi_batched_rowwise_is_lane_dense(one_chip, as_tpu_process):
         dims = [int(x) for x in shape.group(2).split(",") if x]
         assert 265 not in dims, line
         if shape.group(1) != "f32":
+            continue
+        dense, tiled = _tiled_bytes(*shape.groups()[1:], 4)
+        assert tiled <= 2 * dense, line
+
+
+def test_taxi_max_batched_rowwise_is_lane_dense(one_chip, as_tpu_process):
+    """The MAX viz's twin of the plan above (B = 2 over 2^23 rows, messages
+    (pu_zone,), (do_zone,) and (day,) of the tropical ring, σ on payment
+    type): every gather is one-hot with the infinity split, no (D, N)
+    one-hot is written, and no f32 array over the rows that the rowwise
+    stage writes is padded to over twice its values (``take`` wrote (N, 2)
+    tiled to 128 lanes).  The split tables, (B, D, 1) each, are a few KiB."""
+    rel = ("pu_zone", "do_zone", "day", "hour", "payment_type", "rate_code")
+    doms = {"pu_zone": 265, "do_zone": 265, "day": 31, "hour": 24,
+            "payment_type": 6, "rate_code": 7}
+    in_canon = (("pu_zone",), ("do_zone",), ("day",))
+    plan = plans_mod._build_batched_sparse_plan(
+        sr.TROPICAL_MAX, rel, doms, in_canon, ("payment_type",), ("hour",), ROWS,
+        ({}, {}),
+    )
+    assert plan.gathers == (4, 0, 3)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = sds((ROWS,), jnp.int32)
+    fields = (sds((265,)), sds((265,)), sds((31,)))
+    hlo = _compile(
+        plan.fn,
+        (sds((ROWS,)),) * 2, (fields,) * 2, (rows,) * 3,
+        ((sds((6,), jnp.bool_),),) * 2, (rows,), rows,
+    ).as_text()
+    written = list(_written(hlo, "rowwise"))
+    assert any(s.group(1) == "f32" and ROWS in [int(x) for x in s.group(2).split(",")]
+               for s, _ in written)
+    for shape, line in written:
+        dims = [int(x) for x in shape.group(2).split(",") if x]
+        assert not ({265, 31} & set(dims) and ROWS in dims), line
+        if shape.group(1) != "f32" or ROWS not in dims:
             continue
         dense, tiled = _tiled_bytes(*shape.groups()[1:], 4)
         assert tiled <= 2 * dense, line
